@@ -21,7 +21,7 @@ infeasible at the default block size.
 
 ``--impl kernel|ref|all`` (with ``--method lfvt``) picks the walk
 execution layer: ``kernel`` is the ISSUE-5 live row-tiled walk
-(``method='lfvt'`` — Mosaic on TPU, its compiled jnp twin on CPU,
+(``method='lfvt'`` — its compiled jnp twin on every backend,
 DESIGN.md §10) with walk_steps/early_stops stats and the
 ``kernel_vs_ref_walk_ratio`` the CI regression gate tracks; ``ref`` is
 the PR-4 whole-block jnp walk (``method='lfvt_ref'``).

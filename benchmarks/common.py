@@ -9,8 +9,8 @@ top-level schema instead of accreting a JSON file per PR::
 
 ``config`` names the workload cell (e.g. "method_axis/largeW"),
 ``method`` the join family ("lfvt", "bitmap", "mr_cf", ...), ``impl``
-the execution layer ("kernel" — Mosaic on TPU / its compiled jnp twin
-elsewhere — or "ref"/"jnp"), and ``metrics`` a flat name -> number
+the execution layer ("kernel" — the live row-tiled walk, run as its
+compiled jnp twin — or "ref"/"jnp"), and ``metrics`` a flat name -> number
 mapping. ``benchmarks/check_regression.py`` diffs two such files by
 (config, method, impl) key; CI fails when a tracked metric regresses.
 """

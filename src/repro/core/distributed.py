@@ -30,10 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .config import global_config
 from .measures import get_measure
@@ -412,10 +409,19 @@ def _kernel_block_pairs(block: ShardBlock, *, t: float, method: str,
 _PEAK_KEYS = ("peak_mask", "peak_inter", "walk_vmem", "waste_max")
 
 
+def _devices(arr) -> set:
+    """Ids of the devices holding shards of a reduce output — where the
+    reduce actually ran (``stats['reduce_devices']`` counts their union
+    over a join)."""
+    return {shard.device.id for shard in arr.addressable_shards}
+
+
 def _fold_delta(acc: dict, delta: dict) -> None:
     """Fold a resilience task's stat deltas into a driver accumulator:
-    peaks combine by max, counters by sum, non-numeric keys (the rung
-    name) are dropped."""
+    peaks combine by max, counters by sum, the walk implementation is
+    carried over, other non-numeric keys (the rung name) are dropped."""
+    if delta.get("walk_impl"):
+        acc["walk_impl"] = delta["walk_impl"]
     for k, v in delta.items():
         if k in acc and isinstance(v, (int, float)) \
                 and not isinstance(v, bool):
@@ -495,7 +501,7 @@ def _lfvt_loop_join(R: SetCollection, S: SetCollection, t: float, part,
         return {"reduce": 0, "result": 0, "regrows": 0, "dense": 0,
                 "peak_mask": 0, "peak_inter": 0, "ship": 0, "shards": 0,
                 "walk_steps": 0, "early_stops": 0, "live": 0, "total": 0,
-                "walk_vmem": 0}
+                "walk_vmem": 0, "walk_impl": None}
 
     acc = zero_acc()
 
@@ -575,6 +581,7 @@ def _lfvt_loop_join(R: SetCollection, S: SetCollection, t: float, part,
             acc["total"] += kstats.get("total_tiles", 0)
             acc["walk_vmem"] = max(acc["walk_vmem"],
                                    kstats.get("walk_vmem_tile_bytes", 0))
+            acc["walk_impl"] = kstats.get("walk_impl", acc["walk_impl"])
             mask_cells = len(rs) * flat.n_sets
             acc["peak_mask"] = max(acc["peak_mask"], mask_cells)
             acc["peak_inter"] = max(
@@ -589,6 +596,7 @@ def _lfvt_loop_join(R: SetCollection, S: SetCollection, t: float, part,
             acc["total"] += kstats.get("total_tiles", 0)
             acc["walk_vmem"] = max(acc["walk_vmem"],
                                    kstats.get("walk_vmem_tile_bytes", 0))
+            acc["walk_impl"] = kstats.get("walk_impl", acc["walk_impl"])
             rr, cc = np.nonzero(mask)
             local = (np.stack([rr, cc], axis=1) if len(rr)
                      else np.zeros((0, 2), np.int64))
@@ -677,6 +685,7 @@ def _lfvt_loop_join(R: SetCollection, S: SetCollection, t: float, part,
             walk_steps=acc["walk_steps"], early_stops=acc["early_stops"],
             live_tiles=acc["live"], total_tiles=acc["total"],
             walk_vmem_tile_bytes=acc["walk_vmem"],
+            walk_impl=acc["walk_impl"],
             regrows=acc["regrows"], pad="ragged", n_buckets=acc["shards"],
             shard_block_bytes=acc["ship"],
             shard_block_bytes_per_shard=acc["ship"] / max(part.n_shards, 1),
@@ -756,12 +765,15 @@ def _lfvt_local_mask(entry_elem, entry_pos, entry_len, seq, nxt, s_sizes,
 
 
 @functools.lru_cache(maxsize=16)
-def _lfvt_submesh(mesh: Mesh, axis: str, k: int) -> Mesh:
-    """First-k-devices submesh for a bucket of k shards (cached so Mesh
-    identity — and with it the jit cache — is stable across calls)."""
-    if tuple(mesh.axis_names) == (axis,) and mesh.shape[axis] == k:
+def _lfvt_submesh(mesh: Mesh, axis: str, first: int, k: int) -> Mesh:
+    """Devices ``[first, first + k)`` of the mesh for a bucket of k shards.
+    Buckets take consecutive device ranges, so every shard of a join has
+    a device of its own. Cached so Mesh identity — and with it the jit
+    cache — is stable across calls."""
+    if (tuple(mesh.axis_names) == (axis,) and first == 0
+            and mesh.shape[axis] == k):
         return mesh
-    return Mesh(mesh.devices.reshape(-1)[:k], (axis,))
+    return Mesh(mesh.devices.reshape(-1)[first:first + k], (axis,))
 
 
 @functools.lru_cache(maxsize=64)
@@ -786,10 +798,10 @@ def _lfvt_walk_fn(mesh: Mesh, axis: str, t: float, measure: str,
         return (mask[None], steps.reshape(1), stops.reshape(1),
                 live.reshape(1))
 
-    # check_rep=False: the walk's while_loop has no replication rule on
-    # jax 0.4.x; every output is per-shard anyway (nothing replicated)
+    # check_vma=False: every output is per-shard (nothing replicated), so
+    # there is no varying-axes invariant to check
     return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,) * 10,
-                             out_specs=(spec,) * 4, check_rep=False))
+                             out_specs=(spec,) * 4, check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)
@@ -804,7 +816,7 @@ def _lfvt_compact_fn(mesh: Mesh, axis: str, cap: int):
         return pairs[None], count.reshape(1)
 
     return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=(spec,) * 2, check_rep=False))
+                             out_specs=(spec,) * 2, check_vma=False))
 
 
 def _lfvt_bucket_arrays(bucket, caps, Lr, r_pad_all, r_sizes_all, R_ids,
@@ -939,11 +951,13 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
                 "waste_sum": 0.0, "waste_max": 0.0, "waste_n": 0}
 
     acc = zero_acc()
+    reduce_devices: set = set()
     cap_hint = pair_capacity if pair_capacity else PAIR_CAP_GRAIN
     tm = global_config.row_tile
 
-    def run_bucket(bucket, caps, lr_b, acc: dict, out_pairs: set) -> None:
-        """One bucket's pack + walk + emit (the mesh rung body)."""
+    def launch_bucket(bucket, caps, lr_b, first: int, acc: dict) -> dict:
+        """One bucket's pack, upload and walk dispatch on devices
+        ``[first, first + K)``; returns device handles, no host sync."""
         K = len(bucket)
         for _, flat, _, _ in bucket:
             checked_flat(flat)  # injected-corruption detection site
@@ -956,7 +970,7 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
         acc["ship"] += 4 * K * int(alloc)
         mp, np_ = caps[0], caps[1]
         acc["dense"] += K * mp * np_
-        submesh = _lfvt_submesh(mesh, axis, K)
+        submesh = _lfvt_submesh(mesh, axis, first, K)
         spec = P(axis)
         fault_point("device_upload")
         placed = tuple(
@@ -964,12 +978,22 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
         fault_point("shard_map")
         masks_dev, steps_dev, stops_dev, live_dev = _lfvt_walk_fn(
             submesh, axis, t, measure, caps[4], tm, schedule)(*placed)
+        return {"K": K, "caps": caps, "lr_b": lr_b, "submesh": submesh,
+                "r_ids": r_ids, "s_ids": s_ids, "masks": masks_dev,
+                "steps": steps_dev, "stops": stops_dev, "live": live_dev}
+
+    def finish_bucket(ctx: dict, acc: dict, out_pairs: set) -> None:
+        """Compact, collect and count one launched bucket."""
+        K, caps, lr_b = ctx["K"], ctx["caps"], ctx["lr_b"]
+        mp, np_ = caps[0], caps[1]
+        r_ids, s_ids, masks_dev = ctx["r_ids"], ctx["s_ids"], ctx["masks"]
+        reduce_devices.update(_devices(masks_dev))
         if emit == "pairs":
             cap = round_capacity(max(cap_hint, 1))
             while True:  # PR-2 regrow: exact counts, compact-only rerun
                 fault_point("compact")
                 pairs_dev, counts_dev = _lfvt_compact_fn(
-                    submesh, axis, cap)(masks_dev)
+                    ctx["submesh"], axis, cap)(masks_dev)
                 counts = np.asarray(counts_dev).reshape(-1)
                 mx = int(counts.max(initial=0))
                 if mx <= cap:
@@ -977,15 +1001,12 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
                 fault_point("regrow")
                 cap = round_capacity(mx)
                 acc["regrows"] += 1
-            for lk in range(K):
-                c = int(counts[lk])
-                if c:
-                    local = np.asarray(pairs_dev[lk, :c])
-                    rid = r_ids[lk, local[:, 0]]
-                    sid = s_ids[lk, local[:, 1]]
-                    keep = (rid >= 0) & (sid >= 0)
-                    out_pairs.update(zip(map(int, rid[keep]),
-                                         map(int, sid[keep])))
+            for lk, local in _shard_prefixes(pairs_dev, counts).items():
+                rid = r_ids[lk, local[:, 0]]
+                sid = s_ids[lk, local[:, 1]]
+                keep = (rid >= 0) & (sid >= 0)
+                out_pairs.update(zip(map(int, rid[keep]),
+                                     map(int, sid[keep])))
             acc["reduce"] += int(counts.sum()) * 8 + K * 4
             acc["result"] += int(counts.sum())
             acc["peak_mask"] = max(acc["peak_mask"], mp * np_)
@@ -1010,9 +1031,9 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
                                    int(cells.max(initial=0)))
             acc["peak_inter"] = max(acc["peak_inter"],
                                     int(cells.max(initial=0)))
-        acc["walk_steps"] += int(np.asarray(steps_dev).sum())
-        acc["early_stops"] += int(np.asarray(stops_dev).sum())
-        acc["live"] += int(np.asarray(live_dev).sum())
+        acc["walk_steps"] += int(np.asarray(ctx["steps"]).sum())
+        acc["early_stops"] += int(np.asarray(ctx["stops"]).sum())
+        acc["live"] += int(np.asarray(ctx["live"]).sum())
         acc["total"] += K * (mp // tm)
         # advisory §10 per-grid-step residency for this bucket's layout
         # (the shard body runs the twin, but the accounting is shared)
@@ -1021,9 +1042,14 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
             acc["walk_vmem"],
             _lw.walk_vmem_tile_bytes(tm, lr_b, np_, caps[3]))
 
+    # buckets take consecutive device ranges, and every walk is launched
+    # before any bucket syncs, so the buckets' shards run side by side
+    first_device = 0
+    launched = []
     for key in sorted(buckets):
         bucket = buckets[key]
         K = len(bucket)
+        first, first_device = first_device, first_device + K
         # mp rounds up to the row-tile multiple: the shard-local walk is
         # row-tiled, and the extra rows are -1-padded with lo = hi = 0
         # (dead lanes — under the planned schedule whole pad tiles are
@@ -1037,7 +1063,7 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
                 max(f.max_seq_len for _, f, _, _ in bucket))
         lr_b = min(max(lr for _, _, _, lr in bucket), Lr) if Lr else 1
         if res is None:
-            run_bucket(bucket, caps, lr_b, acc, pairs)
+            launched.append(launch_bucket(bucket, caps, lr_b, first, acc))
             continue
         # resilience ladder per bucket (DESIGN.md §12): mesh -> per-shard
         # loop walk -> host oracle; an over-budget bucket skips straight
@@ -1047,9 +1073,10 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
         tid = (f"lfvt_mesh/{emit}/{measure}/shards="
                + "-".join(str(k) for k, _, _, _ in bucket))
 
-        def mesh_rung(bucket=bucket, caps=caps, lr_b=lr_b):
+        def mesh_rung(bucket=bucket, caps=caps, lr_b=lr_b, first=first):
             sub_acc, sub_pairs = zero_acc(), set()
-            run_bucket(bucket, caps, lr_b, sub_acc, sub_pairs)
+            finish_bucket(launch_bucket(bucket, caps, lr_b, first, sub_acc),
+                          sub_acc, sub_pairs)
             return sorted_pairs(sub_pairs), sub_acc
 
         def loop_rung(bucket=bucket):
@@ -1095,6 +1122,8 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
         got, delta = res.run(tid, rungs)
         pairs.update((int(a), int(b)) for a, b in got)
         _fold_delta(acc, delta)
+    for ctx in launched:
+        finish_bucket(ctx, acc, pairs)
 
     n_result = acc["result"] if emit == "pairs" else len(pairs)
     if stats is not None:
@@ -1111,9 +1140,11 @@ def _lfvt_mesh_join(R: SetCollection, S: SetCollection, t: float, part,
             # ('static' reports every tile as entered)
             live_tiles=acc["live"], total_tiles=acc["total"],
             walk_schedule=schedule,
+            # the shard body always runs the compiled jnp twin
+            walk_impl="jnp",
             walk_vmem_tile_bytes=acc["walk_vmem"],
             regrows=acc["regrows"], pad=pad, n_buckets=len(buckets),
-            mesh_devices=n_devices,
+            mesh_devices=n_devices, reduce_devices=len(reduce_devices),
             shard_block_bytes=acc["ship"],
             shard_block_bytes_per_shard=acc["ship"] / max(part.n_shards, 1),
             pad_waste_max=acc["waste_max"],
@@ -1136,18 +1167,31 @@ def _emit_shard_pairs(block: ShardBlock, lk: int, local: np.ndarray,
     out.update(zip(map(int, rid[keep]), map(int, sid[keep])))
 
 
+def _shard_prefixes(pairs_dev, counts: np.ndarray) -> dict:
+    """{k: pairs_dev[k, :counts[k]] on the host} for every shard k with
+    pairs, from a stacked (K, cap, 2) buffer.
+
+    Each prefix is sliced on the device that holds that shard
+    (``addressable_shards``), so a mesh-sharded stack is never indexed
+    across devices and only the counted rows cross the host boundary —
+    the cap-sized buffer stays device-resident (reduce output bytes are
+    ``8·n_k + 4`` per shard, the Fig. 8 model)."""
+    out = {}
+    for shard in pairs_dev.addressable_shards:
+        first = shard.index[0].start or 0
+        for j in range(shard.data.shape[0]):
+            c = int(counts[first + j])
+            if c:
+                out[first + j] = np.asarray(shard.data[j, :c])
+    return out
+
+
 def _collect_block_pairs(block: ShardBlock, pairs_dev,
                          counts: np.ndarray, out: set) -> None:
     """Transfer each shard's variable-length pair slice and map the packed
-    (row, col) indices back to original ids.
-
-    Only ``pairs_dev[k, :counts[k]]`` ever crosses the host boundary —
-    the cap-sized buffer stays device-resident (reduce output bytes are
-    ``8·n_k + 4`` per shard, the Fig. 8 model)."""
-    for lk in range(len(counts)):
-        c = int(counts[lk])
-        if c:
-            _emit_shard_pairs(block, lk, np.asarray(pairs_dev[lk, :c]), out)
+    (row, col) indices back to original ids."""
+    for lk, local in _shard_prefixes(pairs_dev, counts).items():
+        _emit_shard_pairs(block, lk, local, out)
 
 
 def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
@@ -1332,6 +1376,7 @@ def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
                 "peak_inter": 0, "live": 0, "total_tiles": 0}
 
     acc = zero_block_acc()
+    reduce_devices: set = set()
 
     def run_block(block, acc: dict, out_pairs: set, use_mesh) -> None:
         """One ShardBlock's reduce + emit (primary / loop rung body)."""
@@ -1355,6 +1400,7 @@ def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
                 block, t=t, method=method, cap_hint=cap_hint,
                 mesh=use_mesh, axis=axis, measure=measure)
             _collect_block_pairs(block, pairs_dev, counts, out_pairs)
+            reduce_devices.update(_devices(pairs_dev))
             # variable-length reduce output: each shard ships its exact
             # slice + one count; the cap buffer never leaves the device
             acc["reduce"] += int(counts.sum()) * 8 + block.n_local * 4
@@ -1376,6 +1422,7 @@ def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
                 masks_dev = _loop_reduce(
                     tuple(jnp.asarray(a) for a in block.arrays),
                     t=t, method=method, measure=measure)
+            reduce_devices.update(_devices(masks_dev))
             masks = np.asarray(masks_dev)
             for lk in range(block.n_local):
                 rr, ss = np.nonzero(masks[lk])
@@ -1457,5 +1504,9 @@ def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
         if kernel_loop:
             stats["live_tiles"] = acc["live"]
             stats["total_tiles"] = acc["total_tiles"]
+        else:
+            stats["reduce_devices"] = len(reduce_devices)
+        if mesh is not None:
+            stats["mesh_devices"] = int(mesh.shape[axis])
         resilience_stats(stats, res)
     return pairs

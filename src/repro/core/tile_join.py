@@ -338,14 +338,15 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             ``repro.join`` front door defaults to this). Or force one:
             'popcount' (bitmaps, VPU, the legacy default) | 'onehot'
             (membership matmul, MXU)
-            | 'kernel_bitmap' | 'kernel_onehot' (Pallas, interpret on CPU)
+            | 'kernel_bitmap' | 'kernel_onehot' (Pallas: Mosaic on TPU,
+            the interpreter elsewhere)
             | 'lfvt' (flat-array LFVT walk, DESIGN.md §9-§10 — S-side
             device memory ~ Σ|seq| tuples plus E ≤ Σ|seq| sparse entry
             rows, never O(U), instead of the |S|·⌈U/32⌉ bitmap sheet;
             the path for large element universes; both emit modes run
-            the live row-tiled walk kernel — Mosaic on TPU, its
-            compiled jnp twin elsewhere — with walk_steps/early_stops/
-            live_tiles stats) | 'lfvt_ref' (the PR-4 whole-block
+            the live row-tiled walk — its compiled jnp twin on every
+            backend, ``stats['walk_impl']`` — with walk_steps/
+            early_stops/live_tiles stats) | 'lfvt_ref' (the PR-4 whole-block
             jnp walk, kept as the reference fallback and the
             `--impl ref` bench axis).
     measure: 'jaccard' | 'cosine' | 'dice' | 'overlap' (DESIGN.md §8) —
@@ -424,7 +425,8 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
     def zero_acc() -> dict:
         return {"out_sparse": 0, "out_dense": 0, "n_pairs": 0, "live": 0,
                 "total_tiles": 0, "regrows": 0, "r_rep_hits": 0,
-                "walk_steps": 0, "early_stops": 0, "walk_vmem": 0}
+                "walk_steps": 0, "early_stops": 0, "walk_vmem": 0,
+                "walk_impl": None}
 
     acc = zero_acc()
 
@@ -435,6 +437,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         acc["early_stops"] += kstats.get("early_stops", 0)
         acc["walk_vmem"] = max(acc["walk_vmem"],
                                kstats.get("walk_vmem_tile_bytes", 0))
+        acc["walk_impl"] = kstats.get("walk_impl", acc["walk_impl"])
 
     def dispatch(start: int, stop: int, acc: dict) -> dict:
         """Launch all of one R block's device work; no host syncs."""
@@ -573,6 +576,8 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
                 "R": collection_digest(R), "S": collection_digest(S)})
 
         def fold(delta: dict) -> None:
+            if delta.get("walk_impl"):
+                acc["walk_impl"] = delta["walk_impl"]
             for k, v in delta.items():
                 if k in acc and isinstance(v, (int, float)) \
                         and not isinstance(v, bool):
@@ -638,6 +643,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             stats["walk_steps"] = acc["walk_steps"]
             stats["early_stops"] = acc["early_stops"]
             stats["walk_vmem_tile_bytes"] = acc["walk_vmem"]
+            stats["walk_impl"] = acc["walk_impl"]
         if method in ("lfvt", "lfvt_ref"):
             # the §9 memory axis: what the flat S rep holds on device vs
             # what the bitmap sheet would have cost at this universe

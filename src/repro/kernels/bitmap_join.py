@@ -36,11 +36,13 @@ from repro.core import measures
 
 __all__ = ["bitmap_join_tiled", "bitmap_join_live_tiled", "DEFAULT_TILES"]
 
-# (TM, TN, TW). HBM traffic per output tile ~ (TM+TN)*TW*4 per k-step, so
-# total bitmap re-reads scale with (1/TM + 1/TN): (256,256) halves traffic
-# vs the (128,128) baseline while the AND intermediate (TM,TN,TW)*4B = 2 MiB
-# + 256 KiB acc stay comfortably inside VMEM (EXPERIMENTS.md §Perf/join).
-DEFAULT_TILES = (256, 256, 8)
+# (TM, TN, TW). On the TPU a word block's last dim must be a multiple of
+# 128 lanes or the whole padded W (``ops.pick_tiles`` shrinks TW to one
+# block spanning a narrow W), so TW = 128. The (TM, TN, TW) uint32 AND
+# intermediate is then 8 MiB at (128, 128, 128), which fits VMEM; the
+# v5e compiler refuses (256, 256, 256) for VMEM. tests/test_tpu_compile.py
+# compiles these tiles for a v5e chip. Their speed is not measured yet.
+DEFAULT_TILES = (128, 128, 128)
 
 
 def _popcount_accumulate(r_bm_ref, s_bm_ref, acc_ref):
@@ -77,7 +79,7 @@ def _kernel(skip_ref, r_bm_ref, s_bm_ref, r_sz_ref, s_sz_ref, lo_ref, hi_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(skip_ref[0, 0] == 0)
+    @pl.when(skip_ref[0, 0, 0] == 0)
     def _accumulate():
         _popcount_accumulate(r_bm_ref, s_bm_ref, acc_ref)
 
@@ -105,6 +107,7 @@ def bitmap_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
     N = s_bitmaps.shape[0]
     assert M % TM == 0 and N % TN == 0 and W % TW == 0, (M, N, W, tiles)
     grid = (M // TM, N // TN, W // TW)
+    n_tiles = grid[1]
 
     kernel = functools.partial(_kernel, t=t, measure=measure,
                                n_kblocks=grid[2], tn=TN)
@@ -112,7 +115,10 @@ def bitmap_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),          # skip
+            # skip flag of tile (i, j): a (1, 1, 1) block over the
+            # (m_tiles * n_tiles, 1, 1) flag column, so its last two dims
+            # are the full array's
+            pl.BlockSpec((1, 1, 1), lambda i, j, k: (i * n_tiles + j, 0, 0)),
             pl.BlockSpec((TM, TW), lambda i, j, k: (i, k)),        # r bitmaps
             pl.BlockSpec((TN, TW), lambda i, j, k: (j, k)),        # s bitmaps
             pl.BlockSpec((TM, 1), lambda i, j, k: (i, 0)),         # r sizes
@@ -124,7 +130,7 @@ def bitmap_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_),
         scratch_shapes=[pltpu.VMEM((TM, TN), jnp.int32)],
         interpret=interpret,
-    )(skip, r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
+    )(skip.reshape(-1, 1, 1), r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
 
 
 # ---------------------------------------------------------------------- #
@@ -149,7 +155,7 @@ def _live_kernel(ti_ref, tj_ref, r_bm_ref, s_bm_ref, r_sz_ref, s_sz_ref,
         q = _qualify_tile(acc_ref[...], r_sz_ref, s_sz_ref, lo_ref, hi_ref,
                           j, t=t, measure=measure, tn=tn)
         mask_ref[...] = q[None]
-        cnt_ref[...] = jnp.sum(q, dtype=jnp.int32).reshape(1, 1)
+        cnt_ref[...] = jnp.sum(q, dtype=jnp.int32).reshape(1, 1, 1)
 
 
 @functools.partial(jax.jit,
@@ -188,16 +194,17 @@ def bitmap_join_live_tiled(tile_i, tile_j, r_bitmaps, r_sizes, s_bitmaps,
         ],
         out_specs=[
             pl.BlockSpec((1, TM, TN), lambda l, k, ti, tj: (l, 0, 0)),
-            pl.BlockSpec((1, 1), lambda l, k, ti, tj: (l, 0)),
+            pl.BlockSpec((1, 1, 1), lambda l, k, ti, tj: (l, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((TM, TN), jnp.int32)],
     )
-    return pl.pallas_call(
+    mask, counts = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((L, TM, TN), jnp.bool_),
-            jax.ShapeDtypeStruct((L, 1), jnp.int32),
+            jax.ShapeDtypeStruct((L, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(tile_i, tile_j, r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
+    return mask, counts.reshape(L, 1)

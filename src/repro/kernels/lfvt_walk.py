@@ -34,11 +34,14 @@ the Mosaic execution layer for that walk:
     traffic — their steps never execute. ``walk_steps``/``early_stops``
     are emitted per tile so drivers can report the savings.
 
-Off-TPU, interpret mode is a correctness harness, not an execution
-path: ``ops.lfvt_walk_join_pairs_dispatch`` runs
+The Mosaic body does not lower for the TPU yet: the compiler refuses
+its 1-D ``seq[safe]`` / ``nxt[safe]`` gathers ("Only 2D gather is
+supported"), and its ``(1, 1)`` counter blocks are not tile-aligned
+either. So ``ops.lfvt_walk_join_pairs_dispatch`` runs
 ``lfvt_walk_live_tiled_ref`` — the XLA-compiled jnp twin of the exact
-same tiled schedule (bit-identical masks/counts/stats) — and reserves
-the interpreted Pallas kernel for the parity tests.
+same tiled schedule (bit-identical masks/counts/stats) — on every
+backend, and the Pallas kernel runs only under the interpreter, as the
+parity harness of the tests.
 """
 from __future__ import annotations
 
@@ -131,8 +134,8 @@ def plan_row_tiles_device(lo, hi, tm: int):
     Computes the exact host criterion (``any(lo < hi)`` per row tile)
     on device, then stable-partitions tile ids so the live ones occupy
     the front in ascending order: the composite key ``dead * m_tiles +
-    tile_id`` makes a plain ``argsort`` order-preserving on every jax
-    version (no ``stable=`` kwarg on 0.4.x). Returns ``(ti_sorted
+    tile_id`` makes a plain ``argsort`` order-preserving without relying
+    on a stable sort. Returns ``(ti_sorted
     (m_tiles,) int32, n_live () int32)`` with ``ti_sorted[:n_live] ==
     plan_row_tiles(lo, hi, tm)``.
     """
@@ -214,7 +217,7 @@ def _qualify(counts, r_sz, s_sz, lo, hi, t, measure):
 
 
 # ---------------------------------------------------------------------- #
-# compiled jnp twin — the off-TPU execution path
+# compiled jnp twin — the execution path on every backend
 # ---------------------------------------------------------------------- #
 def _staircase(ti, lane_pos, lane_rem, nxt2d, seq2d, ssz2d,
                rsz, lo, hi, *, t: float, measure: str,
@@ -296,7 +299,8 @@ def _staircase(ti, lane_pos, lane_rem, nxt2d, seq2d, ssz2d,
 def lfvt_walk_live_tiled_ref(ti, lane_pos, lane_rem, nxt2d, seq2d, ssz2d,
                              rsz, lo, hi, *, t: float, measure: str,
                              max_steps: int, tm: int):
-    """jnp twin of ``lfvt_walk_live_tiled`` — the XLA-compiled CPU path.
+    """jnp twin of ``lfvt_walk_live_tiled`` — the XLA-compiled walk that
+    runs on every backend.
 
     Same live row-tile schedule and per-step algebra as the Mosaic body,
     with two CPU-shaped scheduling changes that leave every output and

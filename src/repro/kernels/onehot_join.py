@@ -27,10 +27,10 @@ from repro.core import measures
 
 __all__ = ["onehot_join_tiled", "onehot_join_live_tiled", "DEFAULT_TILES"]
 
-# (TM, TN, TW): matmul K = TW*32 = 256 (MXU-aligned); TN=256 halves S-side
-# bitmap re-reads vs TN=128 at the cost of a 128 KiB f32 accumulator —
-# still VMEM-cheap (unpacked operands: (256, 256) bf16 = 128 KiB each).
-DEFAULT_TILES = (128, 256, 8)
+# (TM, TN, TW): TW = 128 words (a lane-aligned word block, see
+# bitmap_join.DEFAULT_TILES), so the matmul K = TW*32 = 4096. The v5e
+# compiler fits (128, 128, 128) in VMEM and refuses TN = 256 for VMEM.
+DEFAULT_TILES = (128, 128, 128)
 
 
 def _unpack_bits(words: jax.Array) -> jax.Array:
@@ -38,7 +38,8 @@ def _unpack_bits(words: jax.Array) -> jax.Array:
     rows, tw = words.shape
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
     bits = jnp.bitwise_and(jnp.right_shift(words[:, :, None], shifts), jnp.uint32(1))
-    return bits.reshape(rows, tw * 32).astype(jnp.bfloat16)
+    # Mosaic has no uint32 -> bfloat16 cast; int32 -> bfloat16 lowers
+    return bits.reshape(rows, tw * 32).astype(jnp.int32).astype(jnp.bfloat16)
 
 
 def _matmul_accumulate(r_bm_ref, s_bm_ref, acc_ref):
@@ -71,7 +72,7 @@ def _kernel(skip_ref, r_bm_ref, s_bm_ref, r_sz_ref, s_sz_ref, lo_ref, hi_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(skip_ref[0, 0] == 0)
+    @pl.when(skip_ref[0, 0, 0] == 0)
     def _accumulate():
         _matmul_accumulate(r_bm_ref, s_bm_ref, acc_ref)
 
@@ -93,6 +94,7 @@ def onehot_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
     N = s_bitmaps.shape[0]
     assert M % TM == 0 and N % TN == 0 and W % TW == 0, (M, N, W, tiles)
     grid = (M // TM, N // TN, W // TW)
+    n_tiles = grid[1]
 
     kernel = functools.partial(_kernel, t=t, measure=measure,
                                n_kblocks=grid[2], tn=TN)
@@ -100,7 +102,7 @@ def onehot_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, 1, 1), lambda i, j, k: (i * n_tiles + j, 0, 0)),
             pl.BlockSpec((TM, TW), lambda i, j, k: (i, k)),
             pl.BlockSpec((TN, TW), lambda i, j, k: (j, k)),
             pl.BlockSpec((TM, 1), lambda i, j, k: (i, 0)),
@@ -112,7 +114,7 @@ def onehot_join_tiled(r_bitmaps, r_sizes, s_bitmaps, s_sizes, lo, hi, skip,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_),
         scratch_shapes=[pltpu.VMEM((TM, TN), jnp.float32)],
         interpret=interpret,
-    )(skip, r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
+    )(skip.reshape(-1, 1, 1), r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
 
 
 # ---------------------------------------------------------------------- #
@@ -137,7 +139,7 @@ def _live_kernel(ti_ref, tj_ref, r_bm_ref, s_bm_ref, r_sz_ref, s_sz_ref,
         q = _qualify_tile(acc_ref[...], r_sz_ref, s_sz_ref, lo_ref, hi_ref,
                           j, t=t, measure=measure, tn=tn)
         mask_ref[...] = q[None]
-        cnt_ref[...] = jnp.sum(q, dtype=jnp.int32).reshape(1, 1)
+        cnt_ref[...] = jnp.sum(q, dtype=jnp.int32).reshape(1, 1, 1)
 
 
 @functools.partial(jax.jit,
@@ -169,16 +171,17 @@ def onehot_join_live_tiled(tile_i, tile_j, r_bitmaps, r_sizes, s_bitmaps,
         ],
         out_specs=[
             pl.BlockSpec((1, TM, TN), lambda l, k, ti, tj: (l, 0, 0)),
-            pl.BlockSpec((1, 1), lambda l, k, ti, tj: (l, 0)),
+            pl.BlockSpec((1, 1, 1), lambda l, k, ti, tj: (l, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((TM, TN), jnp.float32)],
     )
-    return pl.pallas_call(
+    mask, counts = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((L, TM, TN), jnp.bool_),
-            jax.ShapeDtypeStruct((L, 1), jnp.int32),
+            jax.ShapeDtypeStruct((L, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(tile_i, tile_j, r_bitmaps, s_bitmaps, r_sizes, s_sizes, lo, hi)
+    return mask, counts.reshape(L, 1)

@@ -15,8 +15,10 @@ array. Only the per-tile counts (4·L bytes) and the packed pair array
 (8·P bytes) ever cross the host↔device boundary — output traffic scales
 with the result size, not O(m·n).
 
-On CPU backends the kernels run with ``interpret=True`` (Python semantics,
-bit-exact); on TPU they compile to Mosaic.
+On TPU the join kernels compile to Mosaic; on other backends they run
+under the Mosaic TPU interpreter (Python semantics, bit-exact). The LFVT
+walk runs its XLA-compiled jnp twin on every backend: the Mosaic walk
+body does not lower yet (``lfvt_walk_join_pairs_dispatch``).
 """
 from __future__ import annotations
 
@@ -45,17 +47,11 @@ __all__ = ["bitmap_join", "onehot_join", "bitmap_join_pairs",
 
 
 def _interpret_default():
-    """Off-TPU, run kernels under the interpreter (exact Python semantics).
-
-    Newer jax exposes ``pltpu.InterpretParams`` (the Mosaic TPU
-    interpreter); on versions without it the generic Pallas interpreter
-    (``interpret=True``) is the correct fallback.
-    """
+    """Compile to Mosaic on TPU; elsewhere run the Mosaic TPU interpreter
+    (exact Python semantics)."""
     if jax.default_backend() == "tpu":
         return False
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams()
-    return True
+    return pltpu.InterpretParams()
 
 
 def pick_tiles(m: int, n: int, w: int, defaults) -> tuple[int, int, int]:
@@ -234,6 +230,9 @@ class PendingPairs:
     # row tiles hold near-identical windows; row_map[packed_row] is the
     # original block row (-1 for capacity padding)
     row_map: jax.Array | None = None
+    # the LFVT walk implementation this dispatch launched ('jnp' twin or
+    # 'pallas' kernel); reported as stats['walk_impl'] at finalize
+    walk_impl: str | None = None
 
 
 def _join_pairs_dispatch(live_fn, defaults, r_bitmaps, r_sizes, s_bitmaps,
@@ -279,6 +278,8 @@ def join_pairs_finalize(pending: PendingPairs, capacity: int | None = None,
         if pending.extras:
             for key, dev in pending.extras.items():
                 stats[key] = int(np.asarray(dev).sum())
+        if pending.walk_impl is not None:
+            stats["walk_impl"] = pending.walk_impl
     if L == 0:
         if stats is not None:
             stats.update(pair_count=0, pair_bytes=0, counts_bytes=0,
@@ -329,6 +330,8 @@ def join_mask_finalize(pending: PendingPairs, m: int, n: int,
         if pending.extras:
             for key, dev in pending.extras.items():
                 stats[key] = int(np.asarray(dev).sum())
+        if pending.walk_impl is not None:
+            stats["walk_impl"] = pending.walk_impl
     if L == 0:
         return np.zeros((m, n), bool)
     masks = np.asarray(pending.masks)  # (L, tm, NP)
@@ -483,14 +486,15 @@ def lfvt_walk_join_pairs_dispatch(flat, r_padded, r_sizes, lo, hi, t: float,
     schedule collapsed to one dimension, each surviving tile owning a
     VMEM-resident ``(row_tile, n)`` count tile for the whole walk.
 
-    impl: None/'auto' — Mosaic kernel on TPU, the XLA-compiled jnp twin
-          elsewhere (interpret mode is a correctness harness, not an
-          execution path); 'pallas' — force the Pallas kernel (interpret
-          off-TPU; what the parity tests pin); 'jnp' — force the twin.
-          The lane state is VMEM-fed (BlockSpec'd tiles, not SMEM scalar
-          prefetch), so there is no size-based fallback anymore — the
-          per-step working set is reported instead
-          (``walk_vmem_tile_bytes`` via ``PendingPairs.extras``).
+    impl: None/'auto'/'jnp' — the XLA-compiled jnp twin, on every
+          backend; 'pallas' — the Pallas kernel under the interpreter,
+          the parity harness the tests pin. The Mosaic walk body does
+          not lower for the TPU (its 1-D ``seq``/``nxt`` gathers are
+          refused, ROADMAP Speed 3), so 'pallas' on a TPU raises
+          ``NotImplementedError`` instead of compiling. The launched
+          implementation is reported as ``stats['walk_impl']``, and the
+          per-step VMEM working set the kernel would need as
+          ``walk_vmem_tile_bytes`` (via ``PendingPairs.extras``).
     schedule: 'host' (default) — ``plan_row_tiles`` builds the live-tile
           list on host and only live tiles are staged (zero dead grid
           steps); 'device' — ``plan_row_tiles_device`` computes the same
@@ -515,9 +519,15 @@ def lfvt_walk_join_pairs_dispatch(flat, r_padded, r_sizes, lo, hi, t: float,
         if impl in (None, "auto") and hints.get("impl"):
             impl = hints["impl"]
     if impl in (None, "auto"):
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = "jnp"
     if impl not in ("pallas", "jnp"):
         raise ValueError(f"unknown lfvt walk impl {impl!r}")
+    if impl == "pallas":
+        interpret = _interpret_default() if interpret is None else interpret
+        if interpret is False:
+            raise NotImplementedError(
+                "the Mosaic LFVT walk does not lower for the TPU (1-D "
+                "gathers in its body); use impl='jnp'")
     if schedule not in ("host", "device"):
         raise ValueError(f"unknown walk schedule {schedule!r}")
     tm = row_tile or global_config.row_tile
@@ -562,8 +572,6 @@ def lfvt_walk_join_pairs_dispatch(flat, r_padded, r_sizes, lo, hi, t: float,
         # accounted at (replaces the SMEM budget)
         "walk_vmem_tile_bytes": _lw.walk_vmem_tile_bytes(
             tm, Lr, ssz2d.shape[1], seq2d.shape[1])}
-    if impl == "pallas":
-        interpret = _interpret_default() if interpret is None else interpret
     if schedule == "device":
         # traced plan: the live count stays a device scalar (no sync at
         # dispatch); finalize folds it over the staged-tile default via
@@ -596,7 +604,7 @@ def lfvt_walk_join_pairs_dispatch(flat, r_padded, r_sizes, lo, hi, t: float,
     return PendingPairs(
         masks, counts, tile_i, jnp.zeros(L, jnp.int32),
         tm, ssz2d.shape[1], L, m_tiles, m * n,
-        extras=extras, row_map=row_map)
+        extras=extras, row_map=row_map, walk_impl=impl)
 
 
 def lfvt_walk_join_pairs(flat, r_padded, r_sizes, lo, hi, t: float,

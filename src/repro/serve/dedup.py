@@ -85,7 +85,8 @@ class DedupServeEngine:
         self._next_rid = 0
         self.stats = {"requests": 0, "batches": 0, "dups": 0, "admitted": 0,
                       "intra_batch_dups": 0, "pair_count": 0,
-                      "walk_steps": 0, "early_stops": 0, "live_tiles": 0}
+                      "walk_steps": 0, "early_stops": 0, "live_tiles": 0,
+                      "walk_impl": None}
 
     # -------------------------------------------------------------- #
     @property
@@ -185,6 +186,8 @@ class DedupServeEngine:
         self.stats["batches"] += 1
         for key in ("pair_count", "walk_steps", "early_stops", "live_tiles"):
             self.stats[key] += int(batch_stats.get(key, 0))
+        self.stats["walk_impl"] = batch_stats.get("walk_impl",
+                                                  self.stats["walk_impl"])
         out = []
         for i, (rid, _, t_enq) in enumerate(batch):
             is_dup = bool(matches[i])

@@ -166,10 +166,7 @@ def body(xs, err):
     out, new_err = compressed_psum(xs[0], "data", err[0])
     return out[None], new_err[None]
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
                       out_specs=(P("data"), P("data"))))
 err = jnp.zeros_like(x)

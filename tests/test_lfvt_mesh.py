@@ -187,6 +187,9 @@ for meas in ("jaccard", "cosine", "dice", "overlap"):
                                 measure=meas, stats=st)
             assert got == oracle, (meas, emit, pad)
             assert st["mesh_devices"] == 4 and st["n_shards"] == 4
+            # every shard walks on a device of its own, also when the
+            # shards fall into several buckets
+            assert st["reduce_devices"] == 4, (pad, st["n_buckets"])
             assert st["walk_steps"] > 0
             waste[pad] = st["flat_pad_waste"]
     print(meas, "OK", len(oracle))
