@@ -1,0 +1,16 @@
+"""Least HBM time of the traced calls (bench/work.py's bytes at the
+chip's HBM peak) over the device time of the ops inside their
+``bench.serve_step`` spans, in percent. An HBM bound."""
+
+SPAN = "bench.serve_step"
+
+
+def read(run):
+    t = run.trace
+    if t is None:
+        return None
+    device_s = t["span_device_s"].get(SPAN, 0.0)
+    least = run.record["least_bytes"].get(SPAN, 0)
+    if device_s <= 0 or least <= 0:
+        return None
+    return 100.0 * least / run.peaks["hbm_bytes_per_s"] / device_s
