@@ -1,0 +1,9 @@
+"""Host waiting on the device (``repro.sync``: each block's count read
+and its pair transfer, which waits out the compaction), mean ms a
+call."""
+from spans import ms_per_root, window_roots
+
+
+def read(run):
+    return ms_per_root(window_roots(run, "repro.join", "calls"),
+                       {"repro.sync"})

@@ -1,7 +1,6 @@
 """Benchmark entrypoint: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. Roofline aggregation reads
-the dry-run artifacts if present (results/) and is skipped otherwise.
+Prints ``name,us_per_call,derived`` CSV rows.
 """
 from __future__ import annotations
 
@@ -11,8 +10,7 @@ import traceback
 
 def main() -> None:
     from . import (bench_kernels, bench_partition, bench_scale,
-                   bench_shuffle_bytes, bench_speedup, bench_threshold,
-                   roofline)
+                   bench_shuffle_bytes, bench_speedup, bench_threshold)
     suites = [
         ("fig9_threshold", bench_threshold.main),
         ("fig8_partition", bench_partition.main),
@@ -20,7 +18,6 @@ def main() -> None:
         ("table3_disk", bench_shuffle_bytes.main),
         ("fig6_7_speedup", bench_speedup.main),
         ("kernels", bench_kernels.main),
-        ("roofline_table", roofline.main),
     ]
     print("name,us_per_call,derived")
     failed = 0

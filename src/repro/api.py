@@ -21,6 +21,7 @@ from typing import Any, Iterable, Mapping
 
 import numpy as np
 
+from . import obs
 from .core.config import global_config
 from .core.planner import JoinPlan, JoinStats, PlannerError, build_plan
 from .core.sets import SetCollection
@@ -83,6 +84,7 @@ def _dense_mask(pairs: Iterable[tuple[int, int]], R: SetCollection,
     return mask
 
 
+@obs.traced("repro.join")
 def join(R, S, threshold: float, *, measure: str = "jaccard",
          method: str = "auto", emit: str = "pairs",
          n_shards: int | None = None, strategy: str = "load_aware",
@@ -117,9 +119,13 @@ def join(R, S, threshold: float, *, measure: str = "jaccard",
     Every kwarg combination is validated up front through the planner's
     single lattice (:func:`repro.core.planner.validate_join_args`);
     invalid ones raise :class:`~repro.core.planner.PlannerError`.
+
+    Each call is a ``repro.join`` root span (:mod:`repro.obs`), with the
+    planner's ``repro.plan`` and the driver's spans below it.
     """
     R = as_collection(R)
     S = as_collection(S)
+    obs.current().set(m=len(R), n=len(S), method=method)
     mr = n_shards is not None or mesh is not None
     if mr and n_shards is None:
         ax = axis or global_config.mesh_axis
@@ -141,10 +147,12 @@ def join(R, S, threshold: float, *, measure: str = "jaccard",
     if not mr:
         from .core.tile_join import cf_rs_join_device
 
-        plan = build_plan(R, S, threshold, driver="device", method=method,
-                          measure=measure, emit=emit, r_block=r_block,
-                          row_tile=row_tile, pair_capacity=pair_capacity,
-                          double_buffer=double_buffer)
+        with obs.span("repro.plan"):
+            plan = build_plan(R, S, threshold, driver="device",
+                              method=method, measure=measure, emit=emit,
+                              r_block=r_block, row_tile=row_tile,
+                              pair_capacity=pair_capacity,
+                              double_buffer=double_buffer)
         pairs = cf_rs_join_device(R, S, threshold, stats=raw, emit=emit,
                                   pair_capacity=pair_capacity,
                                   measure=measure, fault_plan=fault_plan,

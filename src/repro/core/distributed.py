@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from .. import obs
 from .config import global_config
 from .measures import get_measure
 from .partition import Partitioning, hash_partition, load_aware_partition, route
@@ -1313,11 +1314,12 @@ def mr_cf_rs_join(R: SetCollection, S: SetCollection, t: float,
     # inputs — per shard on the loop path, one homogeneous pick under a
     # mesh (stacked shard_map shapes can't mix rep families)
     if plan is None:
-        plan = build_plan(R, S, t, driver="mr", method=method,
-                          measure=measure, emit=emit,
-                          has_mesh=mesh is not None, pad=pad,
-                          pad_explicit=pad_explicit, schedule=schedule,
-                          pair_capacity=pair_capacity, part=part)
+        with obs.span("repro.plan"):
+            plan = build_plan(R, S, t, driver="mr", method=method,
+                              measure=measure, emit=emit,
+                              has_mesh=mesh is not None, pad=pad,
+                              pad_explicit=pad_explicit, schedule=schedule,
+                              pair_capacity=pair_capacity, part=part)
     method = plan.method
     shard_methods = plan.shard_methods
     if stats is not None:

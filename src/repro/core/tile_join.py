@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import measures
 from .config import global_config
 from .planner import build_plan
@@ -235,6 +236,7 @@ def clear_s_rep_cache() -> None:
     _S_REP_CACHE.clear()
 
 
+@obs.traced("repro.s_rep")
 def _s_device_rep(S: SetCollection, family: str, W: int,
                   stats: dict | None = None):
     """-> (sorted collection, device rep, device sizes, np sizes).
@@ -252,6 +254,7 @@ def _s_device_rep(S: SetCollection, family: str, W: int,
     key = (("bitmap", W) if family == "bitmap" else
            ("lfvt",) if family == "lfvt" else ("padded",))
     hit = "sorted" in entry and key in entry
+    obs.current().set(hit=hit)
     if "sorted" not in entry:
         # None = "the key itself is already sorted": the cache value must
         # not hold a strong reference to its own WeakKeyDictionary key,
@@ -295,6 +298,7 @@ def clear_r_block_cache() -> None:
     _R_BLOCK_CACHE.clear()
 
 
+@obs.traced("repro.r_rep")
 def _r_block_rep(R: SetCollection, family: str, W: int, start: int,
                  stop: int):
     """-> (device rep of R[start:stop], cache_hit). Host rep is memoized on
@@ -311,6 +315,7 @@ def _r_block_rep(R: SetCollection, family: str, W: int, start: int,
     key = (family, W, start, stop) if family == "bitmap" else (
         "padded", start, stop)
     hit = key in entry
+    obs.current().set(hit=hit)
     if hit:
         entry[key] = entry.pop(key)  # LRU: move to the fresh end
     else:
@@ -384,8 +389,9 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
     method = plan.method
     r_block = plan.r_block or global_config.r_block
     double_buffer = plan.double_buffer
-    R.validate()
-    S.validate()
+    with obs.span("repro.validate"):
+        R.validate()
+        S.validate()
     if global_config.strict_validation and (not len(R) or not len(S)):
         side = "R" if not len(R) else "S"
         raise EmptyCollectionError(
@@ -439,6 +445,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
                                kstats.get("walk_vmem_tile_bytes", 0))
         acc["walk_impl"] = kstats.get("walk_impl", acc["walk_impl"])
 
+    @obs.traced("repro.dispatch")
     def dispatch(start: int, stop: int, acc: dict) -> dict:
         """Launch all of one R block's device work; no host syncs."""
         sl = slice(start, stop)
@@ -504,6 +511,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             blk["packed"] = _compact_mask(mask, size=spec_cap)
         return blk
 
+    @obs.traced("repro.gather")
     def finalize(blk: dict, acc: dict, out_pairs: set) -> None:
         """Sync one block's count, regrow if the speculation overflowed,
         and fold its pairs into the result set."""
@@ -513,13 +521,16 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             kstats: dict = {}
             pp, n_pairs = kops.join_pairs_finalize(
                 blk["pending"], capacity=pair_capacity, stats=kstats)
-            local = np.asarray(pp[:n_pairs] if n_pairs else pp[:0])
+            head = pp[:n_pairs] if n_pairs else pp[:0]
+            with obs.span("repro.sync"):  # waits out the compaction
+                local = np.asarray(head)
             acc["out_sparse"] += 8 * n_pairs + 4 + kstats.get(
                 "counts_bytes", 0)
             acc["regrows"] += kstats.get("regrows", 0)
             fold_kernel_stats(acc, kstats)
         elif emit == "pairs":
-            n_pairs = int(blk["total"])  # the only host sync per block
+            with obs.span("repro.sync"):
+                n_pairs = int(blk["total"])  # the block's first host sync
             cap = spec_cap
             if cap < n_pairs:  # overflow: regrow exactly once (count known)
                 fault_point("regrow")
@@ -528,8 +539,12 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
                 acc["regrows"] += 1
             # device-side slice: only the n_pairs rows + the count cross
             # the host boundary; the cap buffer stays device-resident
-            local = (np.asarray(blk["packed"][:n_pairs])
-                     if cap else np.zeros((0, 2), np.int64))
+            if cap:
+                head = blk["packed"][:n_pairs]  # compiles per new count
+                with obs.span("repro.sync"):  # waits out the compaction
+                    local = np.asarray(head)
+            else:
+                local = np.zeros((0, 2), np.int64)
             acc["out_sparse"] += 8 * n_pairs + 4
         else:
             if "mask_pending" in blk:
@@ -549,6 +564,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             sid = Ss.ids[local[:, 1]]
             out_pairs.update(zip(map(int, rid), map(int, sid)))
         acc["n_pairs"] += n_pairs
+        obs.current().set(pairs=n_pairs)
 
     if res is None:
         in_flight: dict | None = None

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.config import global_config
 from repro.core.resilience import fault_point
 from repro.core.tile_join import PAIR_CAP_GRAIN, round_capacity
@@ -271,13 +272,17 @@ def join_pairs_finalize(pending: PendingPairs, capacity: int | None = None,
     """Sync a dispatched join's counts and compact -> (pairs, n_pairs)."""
     fault_point("compact")
     L = pending.live_tiles
+    with obs.span("repro.sync"):
+        # the first blocking reads: the walk counters and per-tile counts
+        extras = ({key: int(np.asarray(dev).sum())
+                   for key, dev in pending.extras.items()}
+                  if stats is not None and pending.extras else {})
+        counts_np = np.asarray(pending.counts)[:, 0] if L else None
     if stats is not None:
         stats["live_tiles"] = L
         stats["total_tiles"] = pending.total_tiles
         stats["dense_mask_bytes"] = pending.dense_mask_bytes
-        if pending.extras:
-            for key, dev in pending.extras.items():
-                stats[key] = int(np.asarray(dev).sum())
+        stats.update(extras)
         if pending.walk_impl is not None:
             stats["walk_impl"] = pending.walk_impl
     if L == 0:
@@ -287,7 +292,6 @@ def join_pairs_finalize(pending: PendingPairs, capacity: int | None = None,
         return jnp.zeros((0, 2), jnp.int32), 0
     # per-tile counts are exact even when a capacity hint is too small:
     # they tell us the regrown capacity without a second kernel pass.
-    counts_np = np.asarray(pending.counts)[:, 0]
     total = int(counts_np.sum())
     cap = round_capacity(total if capacity is None else capacity)
     regrows = 0

@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.core.config import global_config
 from repro.core.lfvt_flat import IncrementalLFVT
 from repro.core.sets import SetCollection, similarity
@@ -124,6 +125,7 @@ class DedupServeEngine:
         return out
 
     # -------------------------------------------------------------- #
+    @obs.traced("repro.serve.dispatch")
     def _dispatch(self, batch):
         """Pad one micro-batch of requests and launch the walk kernel
         (device handles only — no host sync happens here)."""
@@ -146,11 +148,13 @@ class DedupServeEngine:
             measure=self.measure, impl=self.impl, schedule=self.schedule)
         return pending, flat
 
+    @obs.traced("repro.serve.finalize")
     def _finalize(self, batch, pending, flat) -> list[DedupResult]:
         """Sync one dispatched batch, run admission, emit results."""
         batch_stats: dict = {}
         pairs, _ = ops.join_pairs_finalize(pending, stats=batch_stats)
-        pairs = np.asarray(pairs)
+        with obs.span("repro.sync"):  # waits out the compaction
+            pairs = np.asarray(pairs)
         pairs = pairs[pairs[:, 0] >= 0]
         matches: list[list[int]] = [[] for _ in batch]
         for r, c in pairs:
@@ -212,9 +216,14 @@ class DedupServeEngine:
         """Serve one micro-batch from the queue ([] when idle)."""
         if not self._queue:
             return []
-        batch = self._pop_batch()
-        return self._finalize(batch, *self._dispatch(batch))
+        with obs.span("repro.serve.step") as sp:
+            batch = self._pop_batch()
+            now = time.perf_counter()  # queue wait: submit to this pop
+            sp.set(batch=len(batch), rids=[rid for rid, _, _ in batch],
+                   queue_wait_ms=[(now - t) * 1e3 for _, _, t in batch])
+            return self._finalize(batch, *self._dispatch(batch))
 
+    @obs.traced("repro.serve.drain")
     def drain(self) -> list[DedupResult]:
         """Serve until the queue is empty. With ``admit="none"`` the
         corpus is static, so batch k+1 is dispatched before batch k's
