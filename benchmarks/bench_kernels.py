@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.core.join import brute_force_join
 from repro.core.sets import SetCollection
-from repro.core.tile_join import (_compact_mask, _mask_total, _onehot_qualify,
+from repro.core.tile_join import (_compact_mask, _onehot_qualify,
                                   _popcount_qualify, cf_rs_join_device,
                                   popcount_row_block, round_capacity,
                                   window_bounds)
@@ -131,13 +131,13 @@ def main(measures=("jaccard",)) -> dict:
         _, t_oh = timed(oh, repeat=3)
 
         # sparse emission: count + on-device compaction + packed transfer
-        n_pairs = int(_mask_total(mask))
+        n_pairs = int(_compact_mask(mask, size=0)[1])
         cap = round_capacity(n_pairs)
 
         def compact():
             if not cap:
                 return np.zeros((0, 2), np.int32)
-            return np.asarray(_compact_mask(mask, size=cap))
+            return np.asarray(_compact_mask(mask, size=cap)[0])
 
         compact()  # compile
         _, t_compact = timed(compact, repeat=3)

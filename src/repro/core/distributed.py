@@ -40,8 +40,8 @@ from .planner import build_plan, validate_join_args
 from .resilience import (build_resilience, checked_flat, collection_digest,
                          fault_point, resilience_stats, sorted_pairs)
 from .sets import EmptyCollectionError, SetCollection
-from .tile_join import (PAIR_CAP_GRAIN, popcount_counts, qualify,
-                        round_capacity, window_bounds)
+from .tile_join import (PAIR_CAP_GRAIN, compact_mask, popcount_counts,
+                        qualify, round_capacity, window_bounds)
 
 __all__ = ["mr_cf_rs_join", "shard_blocks", "local_join_mask", "ShardBlock"]
 
@@ -261,11 +261,11 @@ def _shard_map_reduce(blocks, mesh: Mesh, axis: str, *, t: float, method: str,
 # ---------------------------------------------------------------------- #
 def _shard_pairs_body(mask, cap: int):
     """In-shard compaction: (m, n) bool mask -> ((cap, 2) int32 pairs,
-    exact int32 count). The count is exact even when ``nonzero`` truncates
-    at ``cap`` — the regrow protocol depends on that."""
-    count = jnp.sum(mask, dtype=jnp.int32)
-    rr, cc = jnp.nonzero(mask, size=cap, fill_value=-1)
-    return jnp.stack([rr, cc], axis=1).astype(jnp.int32), count
+    exact int32 count), through the dense-mask compaction of DESIGN.md §6.
+    The count is exact even when the pairs truncate at ``cap`` — the
+    regrow protocol depends on that."""
+    pairs, count, _ = compact_mask(mask, cap)
+    return pairs, count
 
 
 @functools.partial(jax.jit, static_argnames=("t", "method", "cap", "measure"))

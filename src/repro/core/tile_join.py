@@ -22,6 +22,7 @@ size-sorted S:
 from __future__ import annotations
 
 import functools
+import math
 import weakref
 
 import jax
@@ -47,6 +48,7 @@ __all__ = [
     "clear_s_rep_cache",
     "clear_r_block_cache",
     "round_capacity",
+    "compact_mask",
     "PAIR_CAP_GRAIN",
 ]
 
@@ -199,23 +201,74 @@ def round_capacity(n: int) -> int:
     return min(cap, ceiling)
 
 
+# widest chunk of a mask row that the dense compaction resolves at once
+# (a multiple of the 128 lanes)
+_COMPACT_CHUNK = 1024
 
 
-@jax.jit
-def _mask_total(mask):
-    return jnp.sum(mask, dtype=jnp.int32)
+def _compact_chunk(m: int, n: int, size: int) -> int:
+    """Chunk width ``C`` of :func:`compact_mask` over an (m, n) row view
+    at capacity ``size``: 1024, or the row padded to 128 lanes if
+    narrower, halved while the (size, C) in-chunk select would outgrow
+    the mask itself (size · C > m · n), down to 1."""
+    c = min(_COMPACT_CHUNK, -(-n // 128) * 128)
+    while c > 1 and size * c > m * n:
+        c //= 2
+    return c
 
 
-@functools.partial(jax.jit, static_argnames=("size",))
-def _compact_mask(mask, *, size):
-    """Device-side segment compaction of a dense bool mask.
+def compact_mask(mask: jax.Array, size: int):
+    """Scatter-free segment compaction of a dense bool mask (DESIGN.md §6).
 
     Works for any rank: an (m, n) mask packs to (size, 2) (row, col)
-    int32, an (n_shards, m, n) stack to (size, 3) (shard, row, col).
-    Entries past the true count are -1 capacity padding.
+    int32, an (n_shards, m, n) stack to (size, 3) (shard, row, col), in
+    row-major order; entries past the true count are -1 capacity padding.
+    Returns ``(packed, total, live_chunks)``: ``total`` is the exact
+    int32 count of true entries (also when it exceeds ``size``, which the
+    regrow protocol relies on) and ``live_chunks`` the number of chunks
+    holding at least one.
+
+    Two levels, every shape static and no scatter: leading dims fold into
+    rows and each row splits into ``C``-wide chunks (:func:`_compact_chunk`);
+    one pass over the mask counts each chunk; a binary search of the
+    chunk counts' prefix sum puts output slot k in its chunk, and a
+    prefix sum over that chunk alone (size x C) finds the column.
+    Temporaries are O(m · n / C) for the chunk counts and O(size · C)
+    for the select; ``C`` shrinks with a large ``size`` so that the
+    select stays within the mask's own size. Time is one pass over the
+    mask plus the search's size · log2(m · n / C) gathers, which set the
+    pace at a dense regrow (millions of pairs in a block); none of it
+    grows with how much of the mask is false.
     """
-    idx = jnp.nonzero(mask, size=size, fill_value=-1)
-    return jnp.stack(idx, axis=1)
+    shape = mask.shape
+    rank, n = len(shape), shape[-1]
+    m = math.prod(shape[:-1])
+    if m * n == 0:
+        return (jnp.full((size, rank), -1, jnp.int32), jnp.int32(0),
+                jnp.int32(0))
+    C = _compact_chunk(m, n, size)
+    n_c = -(-n // C)
+    rows = jnp.pad(mask.reshape(m, n), ((0, 0), (0, n_c * C - n)))
+    chunks = rows.reshape(m, n_c, C)
+    counts = jnp.sum(chunks, axis=-1, dtype=jnp.int32).reshape(-1)
+    incl = jnp.cumsum(counts)
+    total = incl[-1]
+    live_chunks = jnp.sum(counts > 0, dtype=jnp.int32)
+    k = jnp.arange(size, dtype=jnp.int32)
+    chunk = jnp.minimum(jnp.searchsorted(incl, k, side="right"),
+                        m * n_c - 1).astype(jnp.int32)
+    within = k - (incl[chunk] - counts[chunk])  # rank of slot k in its chunk
+    row, cc = chunk // n_c, chunk % n_c
+    rank_in = jnp.cumsum(chunks[row, cc], axis=1, dtype=jnp.int32)
+    col = cc * C + jnp.sum(rank_in <= within[:, None], axis=1,
+                           dtype=jnp.int32)
+    lead = jnp.unravel_index(row, shape[:-1]) if rank > 1 else ()
+    packed = jnp.stack([*(i.astype(jnp.int32) for i in lead), col], axis=1)
+    packed = jnp.where((k < total)[:, None], packed, -1)
+    return packed, total, live_chunks
+
+
+_compact_mask = jax.jit(compact_mask, static_argnames=("size",))
 
 
 # ------------------------------------------------------------------ #
@@ -432,7 +485,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         return {"out_sparse": 0, "out_dense": 0, "n_pairs": 0, "live": 0,
                 "total_tiles": 0, "regrows": 0, "r_rep_hits": 0,
                 "walk_steps": 0, "early_stops": 0, "walk_vmem": 0,
-                "walk_impl": None}
+                "walk_impl": None, "live_chunks": 0}
 
     acc = zero_acc()
 
@@ -507,8 +560,8 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         if emit == "pairs":
             # speculative on-device compaction at the fixed capacity; the
             # exact count rides along and is synced only at finalize
-            blk["total"] = _mask_total(mask)
-            blk["packed"] = _compact_mask(mask, size=spec_cap)
+            blk["packed"], blk["total"], blk["live"] = _compact_mask(
+                mask, size=spec_cap)
         return blk
 
     @obs.traced("repro.gather")
@@ -529,13 +582,16 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             acc["regrows"] += kstats.get("regrows", 0)
             fold_kernel_stats(acc, kstats)
         elif emit == "pairs":
-            with obs.span("repro.sync"):
-                n_pairs = int(blk["total"])  # the block's first host sync
+            with obs.span("repro.sync"):  # the block's first host sync
+                n_pairs, live = map(int, jax.device_get(
+                    (blk["total"], blk["live"])))
+            acc["live_chunks"] += live
+            obs.current().set(compact_live_chunks=live)
             cap = spec_cap
             if cap < n_pairs:  # overflow: regrow exactly once (count known)
                 fault_point("regrow")
                 cap = round_capacity(n_pairs)
-                blk["packed"] = _compact_mask(blk["mask"], size=cap)
+                blk["packed"] = _compact_mask(blk["mask"], size=cap)[0]
                 acc["regrows"] += 1
             # device-side slice: only the n_pairs rows + the count cross
             # the host boundary; the cap buffer stays device-resident
@@ -649,6 +705,9 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         stats["double_buffered"] = double_buffer
         stats["regrows"] = acc["regrows"]
         stats["r_rep_cache_hits"] = acc["r_rep_hits"]
+        if emit == "pairs" and method in ("popcount", "onehot"):
+            # chunks of the dense mask that held a pair (DESIGN.md §6)
+            stats["compact_live_chunks"] = acc["live_chunks"]
         if kernel_pairs or method in ("lfvt", "lfvt_ref"):
             stats["live_tiles"] = acc["live"]
             stats["total_tiles"] = acc["total_tiles"]

@@ -104,6 +104,37 @@ def test_per_shard_overflow_regrow():
     assert stats2["regrows"] == 0
 
 
+@pytest.mark.parametrize("cap", [128, 1024])
+def test_shard_pairs_body_matches_nonzero_on_skewed_stack(cap):
+    """The in-shard compaction (the dense-mask helper of DESIGN.md §6)
+    gives the pairs and exact count of the ``jnp.nonzero`` form it
+    replaced, shard by shard, over a stack of skewed shard masks."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.distributed import _shard_pairs_body
+
+    rng = np.random.default_rng(31)
+    m, n = 24, 1300
+    stack = np.stack([rng.random((m, n)) < p
+                      for p in (0.0, 0.0005, 0.004, 0.05, 0.5, 1.0)])
+    stack[1, -1, -1] = True  # a shard whose last element qualifies
+
+    def old_body(mask):
+        rr, cc = jnp.nonzero(mask, size=cap, fill_value=-1)
+        return (jnp.stack([rr, cc], axis=1).astype(jnp.int32),
+                jnp.sum(mask, dtype=jnp.int32))
+
+    def per_shard(body):  # the lax.map of the MR loop path
+        return jax.jit(lambda s: jax.lax.map(body, s))(jnp.asarray(stack))
+
+    pairs, counts = per_shard(lambda mk: _shard_pairs_body(mk, cap))
+    ref_pairs, ref_counts = per_shard(old_body)
+    np.testing.assert_array_equal(np.asarray(pairs), np.asarray(ref_pairs))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
+    assert list(np.asarray(counts)) == [int(s.sum()) for s in stack]
+    assert np.asarray(counts).max() > cap  # the dense shards truncate
+
+
 def test_all_empty_and_partial_shards():
     """Shards with no R rows, no S rows, or neither must contribute
     nothing and not disturb packing/compaction."""

@@ -5,10 +5,13 @@ oracle, the overflow/regrow protocol, live-tile grid construction, the
 device-resident S-representation cache, window_bounds edge cases, and the
 output-traffic accounting (bytes ~ result size, not O(m*n)).
 """
+import time
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.core import tile_join
 from repro.core.distributed import mr_cf_rs_join
 from repro.core.join import brute_force_join, cf_rs_join_fvt
@@ -140,6 +143,88 @@ def test_round_capacity():
     # power-of-two multiples only -> O(log) distinct jit signatures
     caps = {ops.round_capacity(k) for k in range(1, 5000)}
     assert len(caps) <= 7
+
+
+# ---------------------------------------------------------------------- #
+# dense-mask compaction: tile_join.compact_mask == np.nonzero, exactly
+# ---------------------------------------------------------------------- #
+def _sparse_mask(rng, shape, n_true):
+    flat = np.zeros(int(np.prod(shape)), bool)
+    flat[rng.choice(flat.size, n_true, replace=False)] = True
+    return flat.reshape(shape)
+
+
+def _last_only(rng, shape, _):
+    mask = np.zeros(shape, bool)
+    mask[(-1,) * len(shape)] = True
+    return mask
+
+
+@pytest.mark.parametrize("make,shape,n_true,size", [
+    (_sparse_mask, (6, 2600), 40, 128),         # n not a multiple of C
+    (_sparse_mask, (9, 300), 25, 128),          # n < C
+    (_sparse_mask, (1, 5000), 30, 128),         # a single row
+    (_sparse_mask, (16, 2100), 500, 256),       # size < total: a prefix
+    (_sparse_mask, (37, 1000), 50, 128),        # size > total
+    (_sparse_mask, (3, 7, 260), 40, 128),       # a shard stack -> (size, 3)
+    (_sparse_mask, (32, 100_000), 300, 512),    # a batch cell's row width
+    (_last_only, (4, 1500), 1, 128),            # the very last element
+    (lambda rng, shape, _: np.zeros(shape, bool), (8, 3000), 0, 128),
+    (lambda rng, shape, _: np.ones(shape, bool), (5, 300), 1500, 2048),
+    (lambda rng, shape, _: np.ones(shape, bool), (4, 1100), 4400, 256),
+    (lambda rng, shape, _: np.zeros(shape, bool), (0, 5), 0, 128),  # empty
+    # dense regrows: totals far above 2,048, the chunk narrowed with size
+    (_sparse_mask, (64, 5000), 96_000, 1 << 17),  # C = 2, size > total
+    (_sparse_mask, (48, 3000), 40_000, 1 << 14),  # C = 8, a prefix
+    (lambda rng, shape, _: np.ones(shape, bool), (3, 40, 700), 84_000,
+     1 << 17),                                    # C = 1, size > m * n
+])
+def test_compact_mask_matches_nonzero(make, shape, n_true, size):
+    rng = np.random.default_rng(int(np.prod(shape)) + n_true)
+    mask = make(rng, shape, n_true)
+    packed, total, live = tile_join._compact_mask(jnp.asarray(mask),
+                                                  size=size)
+    idx = np.stack(np.nonzero(mask), axis=1)
+    expected = np.full((size, mask.ndim), -1, np.int64)
+    expected[:min(size, len(idx))] = idx[:size]
+    packed = np.asarray(packed)
+    assert packed.dtype == np.int32 and packed.shape == expected.shape
+    np.testing.assert_array_equal(packed, expected)  # pairs, order, fill
+    assert int(total) == int(mask.sum()) == n_true
+    # live chunks: C-wide chunks of the rows (leading dims folded), the
+    # row padded with False to a multiple of C
+    n = shape[-1]
+    rows = mask.reshape(-1, n)
+    c = tile_join._compact_chunk(rows.shape[0], n, size)
+    assert size * c <= max(rows.size, size)  # the select stays mask-sized
+    rows = np.pad(rows, ((0, 0), (0, (-n) % c)))
+    chunk_counts = rows.reshape(rows.shape[0], -(-n // c), c).sum(-1)
+    assert int(live) == int((chunk_counts > 0).sum())
+
+
+def test_device_popcount_regrow_counts_live_chunks():
+    """A forced overflow regrows the dense-mask compaction and keeps the
+    pair set exact; the live-chunk counter is summed into the stats."""
+    rng = np.random.default_rng(21)
+    sets = [rng.choice(120, size=rng.integers(2, 10), replace=False)
+            for _ in range(200)]
+    R = SetCollection.from_ragged(sets, universe=120)
+    S = SetCollection.from_ragged(sets, universe=120)
+    expected = cf_rs_join_fvt(R, S, 0.5)
+    assert len(expected) > tile_join.PAIR_CAP_GRAIN
+    stats = {}
+    t0 = time.perf_counter_ns()
+    got = cf_rs_join_device(R, S, 0.5, method="popcount", pair_capacity=1,
+                            stats=stats)
+    assert got == expected
+    assert stats["regrows"] >= 1
+    assert 0 < stats["compact_live_chunks"] <= stats["pair_count"]
+    # each block's repro.gather span carries its own count
+    gathers = [g for g in obs.recent("repro.gather", obs.RING_ROOTS)
+               if g.start_ns >= t0]
+    assert len(gathers) == stats["r_blocks"]
+    assert sum(g.attrs["compact_live_chunks"] for g in gathers) == (
+        stats["compact_live_chunks"])
 
 
 # ---------------------------------------------------------------------- #
