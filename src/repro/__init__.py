@@ -6,6 +6,7 @@ The public surface is the front door::
     import repro
     result = repro.join(R, S, 0.8)          # method='auto' cost model
     result.pairs, result.stats, result.plan
+    repro.self_join(C, 0.8).pairs           # each unordered pair (a < b) once
 
 Everything re-exported here resolves lazily (PEP 562) so ``import
 repro`` stays free of jax/device initialization until a symbol is
@@ -18,6 +19,7 @@ import importlib
 
 _EXPORTS = {
     "join": "repro.api",
+    "self_join": "repro.api",
     "JoinResult": "repro.api",
     "as_collection": "repro.api",
     "JoinPlan": "repro.core.planner",

@@ -26,7 +26,7 @@ from .core.config import global_config
 from .core.planner import JoinPlan, JoinStats, PlannerError, build_plan
 from .core.sets import SetCollection
 
-__all__ = ["join", "JoinResult", "as_collection"]
+__all__ = ["join", "self_join", "JoinResult", "as_collection"]
 
 
 def as_collection(sets, universe: int | None = None) -> SetCollection:
@@ -174,3 +174,56 @@ def join(R, S, threshold: float, *, measure: str = "jaccard",
     mask = _dense_mask(pairs, R, S) if emit == "mask" else None
     return JoinResult(pairs=frozenset(pairs), mask=mask,
                       stats=JoinStats.from_dict(raw), plan=plan)
+
+
+# what repro.join offers and self_join does not, with why
+_SELF_JOIN_LACKS = {
+    "mesh": "the MapReduce self-join is not implemented",
+    "n_shards": "the MapReduce self-join is not implemented",
+    "fault_plan": "the resilience ladder's oracle rung is an R-S join",
+    "checkpoint_dir": "the resilience ladder's oracle rung is an R-S join",
+}
+
+
+@obs.traced("repro.join")
+def self_join(C, threshold: float, *, measure: str = "jaccard",
+              method: str = "auto", **unsupported) -> JoinResult:
+    """Exact similarity self-join of one collection (near-duplicate dedup).
+
+    ``result.pairs`` holds each unordered pair of ``C``'s ids whose
+    similarity under ``measure`` is at least ``threshold`` once, as
+    ``(a, b)`` with ``a < b``; no set is paired with itself. (``repro.join(C,
+    C, t)`` keeps R-S semantics: every ``(a, a)`` and both orders.)
+
+    It runs the single-device tile join's self mode
+    (``cf_rs_join_device(..., self_join=True)``, DESIGN.md §2): the R
+    blocks are row slices of ``C``'s cached sorted device rep, each row
+    meets only later rows, and each block of the dense-mask methods
+    covers only the columns its size windows reach. ``method`` is chosen
+    as in :func:`join`. The call is a ``repro.join`` root span with
+    ``self_join=True``.
+
+    ``mesh``/``n_shards`` (a MapReduce self-join) and
+    ``fault_plan``/``checkpoint_dir`` (the resilience ladder) are not
+    offered: passing one raises :class:`PlannerError` naming it.
+    """
+    if unsupported:
+        name = next(iter(unsupported))
+        if name not in _SELF_JOIN_LACKS:
+            raise TypeError(
+                f"self_join() got an unexpected keyword argument {name!r}")
+        raise PlannerError(f"self_join takes no {name}= "
+                           f"({_SELF_JOIN_LACKS[name]})")
+    C = as_collection(C)
+    obs.current().set(m=len(C), n=len(C), method=method, self_join=True)
+    from .core.tile_join import cf_rs_join_device
+
+    with obs.span("repro.plan"):
+        plan = build_plan(C, C, threshold, driver="device", method=method,
+                          measure=measure)
+    raw: dict[str, Any] = {}
+    pairs = cf_rs_join_device(C, C, threshold, stats=raw, measure=measure,
+                              plan=plan, self_join=True)
+    return JoinResult(pairs=frozenset(pairs), mask=None,
+                      stats=JoinStats.from_dict(raw),
+                      plan=JoinPlan.from_dict(raw["plan"]))

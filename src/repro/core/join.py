@@ -23,6 +23,7 @@ from .sets import SetCollection, similarity
 
 __all__ = [
     "brute_force_join",
+    "brute_force_self_join",
     "cf_rs_join_fvt",
     "cf_rs_join_lfvt",
     "pairs_from_counts",
@@ -45,6 +46,26 @@ def brute_force_join(R: SetCollection, S: SetCollection, t: float,
         for j, Sj in enumerate(S.sets):
             if len(Ri) and len(Sj) and similarity(Ri, Sj, measure) >= t:
                 out.add((int(R.ids[i]), int(S.ids[j])))
+    return out
+
+
+def brute_force_self_join(C: SetCollection, t: float,
+                          measure: str = "jaccard") -> set:
+    """O(n^2) oracle of a self-join: each unordered pair of ``C``'s ids
+    whose sets have ``sim >= t`` once, as ``(min, max)``; no ``(a, a)``.
+
+    A plain loop over rows ``i < j`` with the measure's integer-exact
+    predicate on ``|C_i & C_j|``: no size window, no device.
+    """
+    m = get_measure(measure)
+    sets = [set(map(int, s)) for s in C.sets]
+    out = set()
+    for i, a in enumerate(sets):
+        for j in range(i + 1, len(sets)):
+            b = sets[j]
+            if m.qualifies(len(a & b), len(a), len(b), t):
+                x, y = int(C.ids[i]), int(C.ids[j])
+                out.add((min(x, y), max(x, y)))
     return out
 
 

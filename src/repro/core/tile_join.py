@@ -44,6 +44,7 @@ __all__ = [
     "onehot_counts",
     "qualify",
     "window_bounds",
+    "block_band",
     "cf_rs_join_device",
     "clear_s_rep_cache",
     "clear_r_block_cache",
@@ -164,6 +165,47 @@ def _onehot_qualify(r_pad, r_sz, s_pad, s_sz, col_lo, col_hi, *, t, universe,
     cols = jnp.arange(s_pad.shape[0])[None, :]
     in_window = (cols >= col_lo[:, None]) & (cols < col_hi[:, None])
     return qualify(counts, r_sz, s_sz, t, measure) & in_window
+
+
+@functools.partial(jax.jit, static_argnames=("method", "cols", "t", "universe",
+                                             "measure"))
+def _band_qualify(r_rep, r_sz, s_rep, s_sz, start, col_lo, col_hi, *,
+                  method, cols, t, universe, measure="jaccard"):
+    """``_popcount_qualify`` or ``_onehot_qualify`` over the S rows
+    ``[start, start + cols)`` alone: the block's column band, with
+    ``col_lo``/``col_hi`` and the mask's columns relative to ``start``.
+    The caller keeps ``start + cols <= n`` (:func:`block_band`), so the
+    slice is never clamped."""
+    s_rep = jax.lax.dynamic_slice_in_dim(s_rep, start, cols)
+    s_sz = jax.lax.dynamic_slice_in_dim(s_sz, start, cols)
+    if method == "popcount":
+        return _popcount_qualify(r_rep, r_sz, s_rep, s_sz, col_lo, col_hi,
+                                 t=t, measure=measure)
+    return _onehot_qualify(r_rep, r_sz, s_rep, s_sz, col_lo, col_hi, t=t,
+                           universe=universe, measure=measure)
+
+
+# a block's band is rounded up to a multiple of ceil(n / _BAND_SHARES)
+# columns (itself a multiple of the 128 lanes), so one S compiles at most
+# _BAND_SHARES band widths per block shape
+_BAND_SHARES = 16
+
+
+def block_band(lo: np.ndarray, hi: np.ndarray, n: int) -> tuple[int, int]:
+    """``(start, cols)``: the S columns one block's dense mask covers.
+
+    The columns its rows' windows ``[lo, hi)`` reach are ``[min lo,
+    max hi)`` over the rows with a non-empty window (Lemma 3.1 over a
+    size-sorted S, DESIGN.md §2); the width is rounded up to the bucket
+    grain and capped at ``n``, and the start moved down so that the band
+    ends inside S. ``cols == n`` means the whole S (start 0)."""
+    grain = -(-max(n, 1) // _BAND_SHARES)
+    grain = -(-grain // 128) * 128
+    live = lo < hi
+    a = int(lo[live].min()) if live.any() else 0
+    b = int(hi[live].max()) if live.any() else 0
+    cols = min(n, -(-max(b - a, 1) // grain) * grain)
+    return min(a, n - cols), cols
 
 
 # Capacity rounding for the jitted compactions (static output size):
@@ -387,7 +429,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
                       measure: str = "jaccard",
                       fault_plan=None,
                       checkpoint_dir: str | None = None,
-                      plan=None) -> set:
+                      plan=None, *, self_join: bool = False) -> set:
     """Candidate-free device join. Returns {(r_id, s_id)}.
 
     method: 'auto' — the cost-model planner (core/planner.py,
@@ -430,6 +472,22 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
 
     ``r_block`` and ``double_buffer`` default to ``global_config``
     (core/config.py) when None.
+
+    self_join: R must be S. Returns each unordered pair ``{a, b}`` of
+            S's ids with ``sim >= t`` once, as ``(min, max)``, and no
+            ``(a, a)`` (``repro.self_join``; DESIGN.md §2). The R blocks
+            are row slices of the cached sorted S rep (no second encode
+            or upload) and row ``i``'s window starts at ``max(lo_i,
+            i + 1)``, so each pair is found once, from its earlier
+            sorted row. The resilience ladder (``fault_plan``,
+            ``checkpoint_dir``, ``REPRO_FAULT``) is not taken.
+
+    Each block of the dense-mask methods (``popcount``, ``onehot``)
+    computes only its column band (:func:`block_band`): the columns its
+    windows reach, bucketed. Where the band is all of S (every block of
+    a randomly ordered R), the program is the unbanded one. The Pallas
+    and walk methods take the windows (the raised ``lo`` too) without
+    the band. ``stats["band_cells"]`` sums rows x band columns.
     """
     # kwarg lattice + method='auto' resolution live in the plan builder
     # (ISSUE 10): the legacy kwargs of this signature are overrides onto
@@ -442,6 +500,14 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
     method = plan.method
     r_block = plan.r_block or global_config.r_block
     double_buffer = plan.double_buffer
+    if self_join:
+        if R is not S:
+            raise ValueError("self_join=True joins one collection: pass it "
+                             "as both R and S")
+        if fault_plan is not None or checkpoint_dir is not None:
+            raise ValueError(
+                "self_join=True takes no fault_plan/checkpoint_dir: the "
+                "resilience ladder's oracle rung is an R-S join")
     with obs.span("repro.validate"):
         R.validate()
         S.validate()
@@ -449,13 +515,14 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         side = "R" if not len(R) else "S"
         raise EmptyCollectionError(
             f"empty {side} collection (strict_validation is on)")
-    res = build_resilience(checkpoint_dir, fault_plan)
+    res = None if self_join else build_resilience(checkpoint_dir, fault_plan)
     if not len(R) or not len(S):
         if stats is not None:  # consumers index these unconditionally
             stats.update(method=method, emit=emit, r_blocks=0, pair_count=0,
                          output_bytes=0, dense_mask_bytes=0,
                          double_buffered=double_buffer, regrows=0,
-                         r_rep_cache_hits=0, plan=plan.to_dict())
+                         r_rep_cache_hits=0, band_cells=0,
+                         plan=plan.to_dict())
             resilience_stats(stats, res)
         return set()
     family = ("lfvt" if method in ("lfvt", "lfvt_ref") else
@@ -463,11 +530,21 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
     universe = max(R.universe, S.universe)
     W = max((universe + 31) // 32, 1)
     Ss, s_rep, s_sz, s_sizes = _s_device_rep(S, family, W, stats)
+    n = len(Ss)
+    if self_join:
+        # R is S in size order: its blocks are row slices of S's device
+        # rep (the walk reads R as padded lists, S's second cached rep)
+        R = Ss
+        r_src = (_s_device_rep(S, "padded", W)[1] if family == "lfvt"
+                 else s_rep)
     r_sizes_all = R.sizes()
     # int32 exactness guard for the device predicate (DESIGN.md §8)
     measures.get_measure(measure).validate(
         t, max(int(r_sizes_all.max(initial=0)), int(s_sizes.max(initial=0))))
     lo_all, hi_all = window_bounds(r_sizes_all, s_sizes, t, measure)
+    if self_join:
+        # the triangle: row i meets only later rows
+        lo_all = np.maximum(lo_all, np.arange(1, n + 1))
 
     kernel_methods = ("kernel_bitmap", "kernel_onehot", "lfvt", "lfvt_ref")
     kernel_pairs = method in kernel_methods and emit == "pairs"
@@ -485,7 +562,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         return {"out_sparse": 0, "out_dense": 0, "n_pairs": 0, "live": 0,
                 "total_tiles": 0, "regrows": 0, "r_rep_hits": 0,
                 "walk_steps": 0, "early_stops": 0, "walk_vmem": 0,
-                "walk_impl": None, "live_chunks": 0}
+                "walk_impl": None, "live_chunks": 0, "band_cells": 0}
 
     acc = zero_acc()
 
@@ -502,13 +579,21 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
     def dispatch(start: int, stop: int, acc: dict) -> dict:
         """Launch all of one R block's device work; no host syncs."""
         sl = slice(start, stop)
-        r_rep, hit = _r_block_rep(R, family, W, start, stop)
-        acc["r_rep_hits"] += hit
+        if self_join:
+            r_rep = jax.lax.dynamic_slice_in_dim(r_src, start, stop - start)
+        else:
+            r_rep, hit = _r_block_rep(R, family, W, start, stop)
+            acc["r_rep_hits"] += hit
         r_sz = jnp.asarray(r_sizes_all[sl])
-        lo = jnp.asarray(lo_all[sl])
-        hi = jnp.asarray(hi_all[sl])
+        band_start, band_cols = (block_band(lo_all[sl], hi_all[sl], n)
+                                 if method in ("popcount", "onehot")
+                                 else (0, n))
+        obs.current().set(band_start=band_start, band_cols=band_cols)
+        acc["band_cells"] += (stop - start) * band_cols
+        lo = jnp.asarray(lo_all[sl] - band_start)
+        hi = jnp.asarray(hi_all[sl] - band_start)
         acc["out_dense"] += (stop - start) * len(Ss)
-        blk: dict = {"start": start}
+        blk: dict = {"start": start, "band_start": band_start}
         if kernel_pairs:
             # live-tile schedule + in-kernel counts; count sync deferred
             if method == "kernel_bitmap":
@@ -542,7 +627,11 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
                     s_rep, r_rep, r_sz, lo, hi, t, measure=measure)
             blk["mb"] = stop - start
             return blk
-        if method == "popcount":
+        if band_cols < n:
+            mask = _band_qualify(r_rep, r_sz, s_rep, s_sz, band_start, lo, hi,
+                                 method=method, cols=band_cols, t=t,
+                                 universe=universe, measure=measure)
+        elif method == "popcount":
             mask = _popcount_qualify(r_rep, r_sz, s_rep, s_sz, lo, hi, t=t,
                                      measure=measure)
         elif method == "onehot":
@@ -617,7 +706,9 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
             n_pairs = len(local)
         if len(local):
             rid = R.ids[start + local[:, 0]]
-            sid = Ss.ids[local[:, 1]]
+            sid = Ss.ids[blk["band_start"] + local[:, 1]]
+            if self_join:
+                rid, sid = np.minimum(rid, sid), np.maximum(rid, sid)
             out_pairs.update(zip(map(int, rid), map(int, sid)))
         acc["n_pairs"] += n_pairs
         obs.current().set(pairs=n_pairs)
@@ -705,6 +796,7 @@ def cf_rs_join_device(R: SetCollection, S: SetCollection, t: float,
         stats["double_buffered"] = double_buffer
         stats["regrows"] = acc["regrows"]
         stats["r_rep_cache_hits"] = acc["r_rep_hits"]
+        stats["band_cells"] = acc["band_cells"]
         if emit == "pairs" and method in ("popcount", "onehot"):
             # chunks of the dense mask that held a pair (DESIGN.md §6)
             stats["compact_live_chunks"] = acc["live_chunks"]

@@ -34,7 +34,9 @@ __all__ = ["RING_ROOTS", "MAX_SPANS", "COMPILE_EVENT", "Span", "span",
            "traced", "current", "recent"]
 
 RING_ROOTS = 4096   # finished roots kept, oldest dropped first
-MAX_SPANS = 256     # descendants kept per root; the rest count in ``dropped``
+# descendants kept per root; the rest count in ``dropped``. A join opens
+# at most 4 + 5 a block: 1,024 keeps a 100,000-row self-join's 98 blocks
+MAX_SPANS = 1024
 COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 _clock = time.perf_counter_ns

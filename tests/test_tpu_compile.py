@@ -151,3 +151,21 @@ def test_compact_mask_compiles_without_scatter(one_chip, size):
     # an op, not the word: the HLO's metadata names this test's function
     assert not re.search(r"\bscatter\(", compiled.as_text())
     _fits_chip(compiled)
+
+
+@pytest.mark.parametrize("cols", [6272, 31360], ids=["narrowest", "widest"])
+def test_band_qualify_compiles(one_chip, cols):
+    """The self-join's banded popcount (``_band_qualify``) for one
+    1,024-row block of the 100,000-set DBLP corpus (W = 215 words), at
+    the narrowest and the widest band that corpus gives."""
+    m, n, w = 1024, 100_000, 215
+    i32 = jnp.int32
+    args = [_spec((m, w), jnp.uint32, one_chip), _spec((m,), i32, one_chip),
+            _spec((n, w), jnp.uint32, one_chip), _spec((n,), i32, one_chip),
+            _spec((), i32, one_chip), _spec((m,), i32, one_chip),
+            _spec((m,), i32, one_chip)]
+    compiled = tile_join._band_qualify.lower(
+        *args, method="popcount", cols=cols, t=0.8, universe=6864,
+        measure="jaccard").compile()
+    assert compiled.out_info.shape == (m, cols)
+    _fits_chip(compiled)
