@@ -155,15 +155,18 @@ class Measure:
         return max(1, lo)
 
     def window_fraction(self, r_sizes: np.ndarray, s_sizes: np.ndarray,
-                        t: float) -> float:
+                        t: float, *, s_sorted: bool = False) -> float:
         """Mean Lemma-3.1 window width over R as a fraction of |S|.
 
         The planner's probe feature: how much of the comparison sheet the
         size filter leaves alive for this (measure, t, size profile).
-        O(m log n + n log n) on the host; 0.0 for empty inputs.
+        O(m log n + n log n) on the host, O(m log n) where ``s_sorted``
+        says ``s_sizes`` is already int64 ascending (the planner passes a
+        collection's memoized sorted sizes); 0.0 for empty inputs.
         """
         r_sizes = np.asarray(r_sizes, dtype=np.int64)
-        s_asc = np.sort(np.asarray(s_sizes, dtype=np.int64))
+        s_asc = (s_sizes if s_sorted
+                 else np.sort(np.asarray(s_sizes, dtype=np.int64)))
         n = len(s_asc)
         if n == 0 or len(r_sizes) == 0:
             return 0.0

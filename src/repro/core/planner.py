@@ -6,7 +6,8 @@ ones (82x at U=2^21, BENCH_pr4), one-hot only ever pays on matmul
 hardware, and Zipf skew moves the crossover. This module owns the
 decision so the caller no longer has to:
 
-  * :func:`probe_features` — a cheap O(m+n+E) host probe of the inputs
+  * :func:`probe_features` — a cheap host probe of the inputs, O(m + R's
+    elements) against a collection whose element summary is memoized
     (m, n, universe, density, Zipf head mass, the per-measure Lemma-3.1
     window fraction, and the exact expected walk traffic
     sum_a freq_R(a) * freq_S(a));
@@ -52,8 +53,10 @@ from typing import Any, Iterator, Mapping
 
 import numpy as np
 
+from .. import obs
 from .config import global_config
 from .measures import get_measure
+from .sets import element_summary
 
 __all__ = [
     "PlannerError", "JoinPlan", "JoinStats", "probe_features",
@@ -120,17 +123,31 @@ DEFAULT_COEFFS: dict[str, dict[str, float]] = {
 # ---------------------------------------------------------------------- #
 # input probe
 # ---------------------------------------------------------------------- #
-def _rows_view(C, rows):
+def _side_view(C, rows):
+    """(sizes, element summary) of one probe side: the collection's own
+    memo for a whole collection, a fresh summary for a routed row subset
+    (a view no memo can key)."""
     if rows is None:
-        return C.sets, C.sizes()
+        return C.sizes(), C.element_summary()
     rows = np.asarray(rows, dtype=np.int64)
-    return [C.sets[int(i)] for i in rows], C.sizes()[rows]
+    sizes = C.sizes()[rows]
+    return sizes, element_summary([C.sets[int(i)] for i in rows], sizes)
 
 
 def probe_features(R, S, t: float, measure: str = "jaccard",
                    r_rows=None, s_rows=None) -> dict[str, Any]:
     """Cheap host probe of one join (optionally restricted to routed row
-    subsets, for per-shard planning): O(m + n + total elements).
+    subsets, for per-shard planning).
+
+    A whole collection's element summary (``SetCollection.element_summary``:
+    the ``np.unique`` of its elements and its ascending sizes) is memoized
+    on the collection, so against a resident S a call costs O(m + R's
+    elements), plus one O(n + S's elements) summary per collection; where
+    R is S both sides read the one memo. Routed row subsets
+    (``r_rows``/``s_rows``, the MR loop path's ``plan_shard_methods``) are
+    summarized afresh on every probe: O(m + n + their elements). Inside a
+    ``repro.plan`` span the probe adds to its ``probe_cached`` attribute the
+    sides whose summary was already memoized.
 
     ``walk_units`` is the *exact* expected flat-LFVT walk traffic
     ``sum_a freq_R(a) * freq_S(a)`` — every R-side occurrence of element
@@ -140,9 +157,14 @@ def probe_features(R, S, t: float, measure: str = "jaccard",
     the hottest chains, which is what moves the crossover).
     """
     meas = get_measure(measure)
-    r_sets, r_sizes = _rows_view(R, r_rows)
-    s_sets, s_sizes = _rows_view(S, s_rows)
-    m, n = len(r_sets), len(s_sets)
+    cached = sum(rows is None and C.has_rep("element_summary")
+                 for C, rows in ((R, r_rows), (S, s_rows)))
+    sp = obs.current()
+    if sp is not None and sp.name == "repro.plan":
+        sp.set(probe_cached=sp.attrs.get("probe_cached", 0) + cached)
+    r_sizes, (vals_r, cnt_r, _, r_asc) = _side_view(R, r_rows)
+    s_sizes, (vals_s, cnt_s, cnt_s_asc, s_asc) = _side_view(S, s_rows)
+    m, n = len(r_sizes), len(s_sizes)
     universe = int(max(R.universe, S.universe, 1))
     w_words = (universe + 31) // 32
     r_elems = int(np.asarray(r_sizes).sum()) if m else 0
@@ -153,23 +175,19 @@ def probe_features(R, S, t: float, measure: str = "jaccard",
         "max_r": int(np.asarray(r_sizes).max(initial=0)) if m else 0,
         "max_s": int(np.asarray(s_sizes).max(initial=0)) if n else 0,
         "density": s_elems / float(max(n, 1) * universe),
-        "window_frac": meas.window_fraction(r_sizes, s_sizes, t),
+        # R's sizes ascending too: the same widths, and searchsorted runs
+        # several times faster on sorted keys; their mean is exact in any
+        # order (integer partial sums far below 2**53), so the same bits
+        "window_frac": meas.window_fraction(r_asc, s_asc, t, s_sorted=True),
         "walk_units": 0, "distinct_s": 0, "zipf_head_mass": 0.0,
     }
     if not s_elems:
         return feats
-    vals_s, cnt_s = np.unique(
-        np.concatenate([np.asarray(s) for s in s_sets if len(s)]),
-        return_counts=True)
     feats["distinct_s"] = int(len(vals_s))
     k = max(1, math.ceil(global_config.planner_head_frac * len(vals_s)))
     k = min(k, len(vals_s))
-    feats["zipf_head_mass"] = float(
-        np.sort(cnt_s)[-k:].sum() / float(s_elems))
+    feats["zipf_head_mass"] = float(cnt_s_asc[-k:].sum() / float(s_elems))
     if r_elems:
-        vals_r, cnt_r = np.unique(
-            np.concatenate([np.asarray(s) for s in r_sets if len(s)]),
-            return_counts=True)
         idx = np.clip(np.searchsorted(vals_s, vals_r), 0, len(vals_s) - 1)
         hit = vals_s[idx] == vals_r
         feats["walk_units"] = int(

@@ -21,8 +21,8 @@ from typing import Sequence
 import numpy as np
 
 __all__ = ["SetCollection", "CollectionValidationError",
-           "EmptyCollectionError", "length_filter_bounds", "jaccard",
-           "similarity"]
+           "EmptyCollectionError", "element_summary", "length_filter_bounds",
+           "jaccard", "similarity"]
 
 
 class CollectionValidationError(ValueError):
@@ -66,6 +66,24 @@ def _as_ragged(sets: Sequence[np.ndarray]) -> list[np.ndarray]:
     return out
 
 
+def element_summary(sets: Sequence[np.ndarray], sizes: np.ndarray
+                    ) -> tuple[np.ndarray, ...]:
+    """The planner probe's view of some sets: ``(vals, counts, counts_asc,
+    sizes_asc)``.
+
+    ``vals``/``counts`` are ``np.unique`` of every element occurrence with
+    its counts, ``counts_asc`` the counts sorted ascending, and
+    ``sizes_asc`` the set sizes as int64, ascending.
+    """
+    occ = [s for s in sets if len(s)]
+    if occ:
+        vals, counts = np.unique(np.concatenate(occ), return_counts=True)
+    else:
+        vals, counts = np.zeros(0, np.int32), np.zeros(0, np.int64)
+    return (vals, counts, np.sort(counts),
+            np.sort(np.asarray(sizes, dtype=np.int64)))
+
+
 @dataclasses.dataclass(eq=False)
 class SetCollection:
     """A collection of sets over a dense integer universe ``[0, universe)``.
@@ -80,8 +98,8 @@ class SetCollection:
     which lets device-resident representations be cached per collection in
     a ``WeakKeyDictionary`` (see ``tile_join``).
 
-    Derived representations (``sizes``/``bitmaps``/``padded``/``csr``) are
-    memoized on the instance — collections are immutable by convention, and
+    Derived representations (``sizes``/``bitmaps``/``padded``/``csr``/
+    ``element_summary``) are memoized on the instance — collections are immutable by convention, and
     both join drivers re-request the same rep for the same collection many
     times. Cached arrays are returned write-protected.
     """
@@ -176,6 +194,17 @@ class SetCollection:
         return self._memo(
             "sizes",
             lambda: np.asarray([len(s) for s in self.sets], dtype=np.int32))
+
+    def element_summary(self) -> tuple[np.ndarray, ...]:
+        """:func:`element_summary` of the whole collection, memoized: it
+        depends on neither ``t`` nor the measure, so a resident collection
+        pays its O(n + total elements) sort once."""
+        return self._memo("element_summary",
+                          lambda: element_summary(self.sets, self.sizes()))
+
+    def has_rep(self, key) -> bool:
+        """Whether the derived rep under ``key`` is already memoized."""
+        return key in self._reps
 
     def padded(self, pad_to: int | None = None) -> tuple[np.ndarray, np.ndarray]:
         """(n, L) int32 with -1 padding, plus (n,) sizes. Memoized per L."""

@@ -274,6 +274,160 @@ def test_probe_features_exact_counts():
     assert 0.0 <= f["window_frac"] <= 1.0
 
 
+def _probe_reference(R, S, t, measure="jaccard", r_rows=None, s_rows=None):
+    """The probe as it was before the element-summary memo: every call
+    concatenates and ``np.unique``s both sides and sorts S's sizes."""
+    import math
+
+    from repro.core.measures import get_measure
+
+    def view(C, rows):
+        if rows is None:
+            return C.sets, C.sizes()
+        rows = np.asarray(rows, dtype=np.int64)
+        return [C.sets[int(i)] for i in rows], C.sizes()[rows]
+
+    meas = get_measure(measure)
+    r_sets, r_sizes = view(R, r_rows)
+    s_sets, s_sizes = view(S, s_rows)
+    m, n = len(r_sets), len(s_sets)
+    universe = int(max(R.universe, S.universe, 1))
+    r_elems = int(np.asarray(r_sizes).sum()) if m else 0
+    s_elems = int(np.asarray(s_sizes).sum()) if n else 0
+    feats = {
+        "m": m, "n": n, "universe": universe,
+        "w_words": (universe + 31) // 32,
+        "r_elems": r_elems, "s_elems": s_elems,
+        "max_r": int(np.asarray(r_sizes).max(initial=0)) if m else 0,
+        "max_s": int(np.asarray(s_sizes).max(initial=0)) if n else 0,
+        "density": s_elems / float(max(n, 1) * universe),
+        "window_frac": meas.window_fraction(r_sizes, s_sizes, t),
+        "walk_units": 0, "distinct_s": 0, "zipf_head_mass": 0.0,
+    }
+    if not s_elems:
+        return feats
+    vals_s, cnt_s = np.unique(
+        np.concatenate([np.asarray(s) for s in s_sets if len(s)]),
+        return_counts=True)
+    feats["distinct_s"] = int(len(vals_s))
+    k = max(1, math.ceil(global_config.planner_head_frac * len(vals_s)))
+    k = min(k, len(vals_s))
+    feats["zipf_head_mass"] = float(
+        np.sort(cnt_s)[-k:].sum() / float(s_elems))
+    if r_elems:
+        vals_r, cnt_r = np.unique(
+            np.concatenate([np.asarray(s) for s in r_sets if len(s)]),
+            return_counts=True)
+        idx = np.clip(np.searchsorted(vals_s, vals_r), 0, len(vals_s) - 1)
+        hit = vals_s[idx] == vals_r
+        feats["walk_units"] = int(
+            (cnt_r[hit].astype(np.int64) * cnt_s[idx[hit]]).sum())
+    return feats
+
+
+def _probe_case(case):
+    """(R, S, t, measure, r_rows, s_rows) for one probe-equality case."""
+    kind, seed = case[0], case[1]
+    if kind == "rs":
+        _, _, measure, t = case
+        R, S = random_collections(seed, m=40, n=60, universe=200,
+                                  max_size=30)
+        return R, S, t, measure, None, None
+    if kind == "self":
+        _, _, measure, t = case
+        C = random_collections(seed, m=2, n=80, universe=150)[1]
+        return C, C, t, measure, None, None
+    if kind == "universes":   # R and S over universes of different sizes
+        R = random_collections(seed, m=30, universe=1 << 12)[0]
+        S = random_collections(seed + 1, n=50, universe=90)[1]
+        return R, S, 0.6, "dice", None, None
+    if kind == "empty_sets":  # every set of one side empty, or no sets
+        R, S = random_collections(seed)
+        empty = SetCollection.from_ragged([[]] * 5, universe=64)
+        none = SetCollection.from_ragged([], universe=64)
+        pick = {0: (empty, S), 1: (R, empty), 2: (R, none), 3: (none, S)}
+        R, S = pick[seed]
+        return R, S, 0.5, "jaccard", None, None
+    # kind == "rows": the MR loop path's routed row subsets
+    R, S = heavy_collections(seed=seed)
+    part = load_aware_partition(R, S, 0.5, 4)
+    s_rows, r_rows = part.shard_rows(R, S)
+    k = max(range(part.n_shards), key=lambda i: len(r_rows[i]))
+    return R, S, 0.5, "cosine", r_rows[k], s_rows[k]
+
+
+_PROBE_CASES = (
+    [("rs", 11 + i, meas, t) for i, meas in enumerate(MEASURES)
+     for t in (0.5, 0.8)]
+    + [("self", 21, "jaccard", 0.8), ("self", 22, "overlap", 0.7),
+       ("universes", 31), ("universes", 32)]
+    + [("empty_sets", i) for i in range(4)]
+    + [("rows", 41), ("rows", 42)])
+
+
+def _same_bits(a, b):
+    if type(a) is not type(b):
+        return False
+    if isinstance(a, float):
+        return np.float64(a).tobytes() == np.float64(b).tobytes()
+    return a == b
+
+
+@pytest.mark.parametrize("case", _PROBE_CASES,
+                         ids=lambda c: "-".join(map(str, c)))
+def test_probe_matches_reference_bit_for_bit(case, monkeypatch):
+    """The memoized probe's features equal a from-scratch probe key for
+    key and bit for bit, on the call that fills the memo and on the one
+    that reads it; so the plan (method, features, scores) is unchanged."""
+    R, S, t, measure, r_rows, s_rows = _probe_case(case)
+    want = _probe_reference(R, S, t, measure, r_rows, s_rows)
+    for _ in range(2):   # the first probe fills the memo, the second reads it
+        got = probe_features(R, S, t, measure, r_rows=r_rows, s_rows=s_rows)
+        assert list(got) == list(want)
+        bad = {k: (got[k], want[k]) for k in want
+               if not _same_bits(got[k], want[k])}
+        assert not bad, bad
+    if r_rows is None:
+        plan = build_plan(R, S, t, method="auto", measure=measure).to_dict()
+        monkeypatch.setattr(P, "probe_features", _probe_reference)
+        assert plan == build_plan(R, S, t, method="auto",
+                                  measure=measure).to_dict()
+
+
+def _plan_span():
+    from repro import obs
+    (root,) = obs.recent("repro.join", 1)
+    (sp,) = [s for s in root.spans if s.name == "repro.plan"]
+    return sp
+
+
+def test_probe_reads_the_memo_on_the_second_call():
+    """A resident S is summarized once: the second join's probe reads
+    S's memo (``probe_cached`` 1, R being a fresh object), and a
+    self-join's second probe reads both sides (2)."""
+    import repro
+
+    R, S = random_collections(51, m=20, n=30)
+    r_lists = [list(map(int, s)) for s in R.sets]
+    counts = []
+    for _ in range(2):   # raw lists: a fresh R collection every call
+        repro.join(r_lists, S, 0.5)
+        counts.append(_plan_span().attrs["probe_cached"])
+    assert counts == [0, 1]
+    C = random_collections(52, n=40)[1]
+    counts = []
+    for _ in range(2):
+        repro.self_join(C, 0.5)
+        counts.append(_plan_span().attrs["probe_cached"])
+    assert counts == [0, 2]
+    summary = S.element_summary()
+    assert summary is S.element_summary()
+    for arr in summary:
+        assert not arr.flags.writeable
+        with pytest.raises(ValueError):
+            arr[...] = 0
+
+
 def test_plan_shard_methods_tracks_partition():
     R, S = heavy_collections()
     part = load_aware_partition(R, S, 0.5, 4)
