@@ -47,11 +47,14 @@ from repro.core.tile_join import (_compact_mask, _onehot_qualify,
                                   popcount_row_block, round_capacity,
                                   window_bounds)
 from repro.data.synth import make_join_dataset
-from repro.launch.analysis import HBM_BW, PEAK_FLOPS
 
 from .common import bench_row, emit, timed, write_bench_json
 
 T = 0.5
+
+# TPU v5e peaks per chip: HBM bandwidth (B/s), bf16 MXU throughput (flop/s)
+HBM_BW = 819e9
+PEAK_FLOPS = 197e12
 
 # feasibility budget for the dense popcount intermediate on this container
 INTERMEDIATE_BUDGET = 1 << 30

@@ -1,82 +1,58 @@
-"""End-to-end driver: dedup (the paper's join) -> LM training -> checkpoint.
+"""End-to-end example: dedup incoming docs against a curated corpus.
 
 The paper's own LLM use case ([40]): incoming corpus batches are joined
-against the curated corpus with MR-CF-RS-Join; exact near-duplicates are
-dropped before batching; a causal LM trains on the survivors with
-fault-tolerant checkpointing. Scaled for CPU by default — pass
-``--d-model 768 --layers 12`` for a ~100M-param run on real hardware.
+against the curated corpus with MR-CF-RS-Join and exact near-duplicates
+are dropped. ``filter_batch`` joins each batch against the static curated
+corpus; ``filter_stream`` also admits every survivor into the corpus, so
+a duplicate inside the stream is caught against its first copy.
 
-  PYTHONPATH=src python examples/dedup_pipeline.py --steps 40
+  PYTHONPATH=src python examples/dedup_pipeline.py --batches 5
 """
 import argparse
-import tempfile
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
-from repro.core.sets import SetCollection
 from repro.data.pipeline import DedupPipeline
 from repro.data.synth import docs_to_sets
-from repro.models.transformer import build
-from repro.train.checkpoint import CheckpointManager
-from repro.train.optimizer import AdamWConfig
-from repro.train.trainer import Trainer, init_train_state, make_train_step
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int, default=40)
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--batches", type=int, default=5)
     ap.add_argument("--vocab", type=int, default=1024)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--batch", type=int, default=12)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--threshold", type=float, default=0.75)
     args = ap.parse_args()
 
     rng = np.random.default_rng(0)
-    # curated corpus the training data must not duplicate
+    # curated corpus the incoming docs must not duplicate
     curated_docs = rng.integers(0, args.vocab, (64, args.seq))
     curated = docs_to_sets(curated_docs, universe=args.vocab)
     pipe = DedupPipeline(curated, threshold=args.threshold, n_shards=4)
 
-    cfg = ModelConfig("dedup-demo", "dense", args.layers, args.d_model,
-                      n_heads=4, n_kv_heads=2, d_ff=4 * args.d_model,
-                      vocab_size=args.vocab, remat="none")
-    model = build(cfg, tp=1)
-    n_params = sum(np.prod(s.shape) for s in
-                   jax.tree.leaves(model.param_specs(),
-                                   is_leaf=lambda x: hasattr(x, "shape")))
-    print(f"model: {n_params/1e6:.1f}M params")
-
-    dropped_total = 0
-
-    def batch_at(step):
-        nonlocal dropped_total
-        r = np.random.default_rng(1000 + step)
-        docs = r.integers(0, args.vocab, (args.batch + 4, args.seq + 1))
+    batches = []
+    for b in range(args.batches):
+        r = np.random.default_rng(1000 + b)
+        docs = r.integers(0, args.vocab, (args.batch, args.seq))
         # plant near-duplicates of curated docs to give the join real work
-        i = (step * 2) % 60
-        docs[:2, : args.seq] = curated_docs[i: i + 2]
-        kept, stats = pipe.filter_batch(docs)  # rows that survive the join
-        dropped_total += stats["n_dropped"]
-        toks = kept[: args.batch]
-        return {"tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-                "labels": jnp.asarray(toks[:, 1:], jnp.int32)}
+        i = (b * 2) % 60
+        docs[:2] = curated_docs[i: i + 2]
+        docs[:2, :4] = r.integers(0, args.vocab, (2, 4))
+        # and one copy of this batch's last doc, caught only by the stream
+        docs[2] = docs[-1]
+        batches.append(docs)
 
-    with tempfile.TemporaryDirectory() as tmp:
-        mgr = CheckpointManager(tmp, keep=2, async_save=True)
-        opt = AdamWConfig(lr=1e-3, warmup_steps=10, total_steps=args.steps)
-        step_fn = jax.jit(make_train_step(model, opt))
-        trainer = Trainer(step_fn, batch_at, mgr, checkpoint_every=20)
-        state = init_train_state(model, jax.random.key(0))
-        state, metrics, step = trainer.run(state, 0, args.steps)
-        mgr.wait()
-        print(f"trained {step} steps; final loss {float(metrics['loss']):.3f}; "
-              f"dedup dropped {dropped_total} near-duplicate docs; "
-              f"checkpoints at steps {mgr.all_steps()}")
+    dropped = 0
+    for b, docs in enumerate(batches):
+        kept, stats = pipe.filter_batch(docs)
+        dropped += stats["n_dropped"]
+        print(f"filter_batch  batch {b}: kept {len(kept)}/{len(docs)}, "
+              f"dropped {stats['n_dropped']}")
+    kept, stats = pipe.filter_stream(batches)
+    print(f"filter_batch  dropped {dropped} near-duplicate docs in all")
+    print(f"filter_stream dropped {stats['n_dropped']} near-duplicate docs "
+          f"in all (kept {sum(len(k) for k in kept)} of {stats['n_in']})")
 
 
 if __name__ == "__main__":

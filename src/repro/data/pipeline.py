@@ -1,10 +1,9 @@
-"""Training-data pipeline with the paper's join as a first-class stage.
+"""Dedup pipeline with the paper's join as its one stage.
 
 ``DedupPipeline`` runs MR-CF-RS-Join between incoming documents (R) and
-the curated corpus (S): any incoming doc whose token-set Jaccard with a
+the curated corpus (S): any incoming doc whose token-set similarity with a
 curated doc clears the threshold is an exact near-duplicate and is dropped
-before batching — the paper's own LLM-training use case ([40]) and the
-reason the join sits in this framework's data layer for all 10 archs.
+before batching — the paper's own LLM-training use case ([40]).
 
 ``filter_batch`` joins against a *static* corpus, so two identical new
 docs in one stream both survive (neither matches the curated side).

@@ -1,5 +1,5 @@
-"""Synthetic data: (a) join corpora mimicking the paper's 7 datasets,
-(b) deterministic-seek token streams for LM training.
+"""Synthetic data: join corpora mimicking the paper's 7 datasets, and
+token documents turned into element sets for dedup.
 
 Table-1 statistics drive the generators: per-dataset (collection size,
 mean/max set length, universe size, Zipf exponent). Scaled-down by
@@ -15,7 +15,7 @@ import numpy as np
 from repro.core.sets import SetCollection
 
 __all__ = ["DATASETS", "make_join_dataset", "make_skew_dataset",
-           "TokenStream", "docs_to_sets"]
+           "docs_to_sets"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,23 +139,3 @@ def docs_to_sets(token_batches: np.ndarray, shingle: int = 1,
         uni = base * 8
     return SetCollection.from_ragged(sets, universe=uni)
 
-
-@dataclasses.dataclass(frozen=True)
-class TokenStream:
-    """Deterministic-seek synthetic LM data: batch_at(step) is pure in
-    (seed, step) — the property the fault-tolerant loop relies on."""
-
-    vocab_size: int
-    batch: int
-    seq_len: int
-    seed: int = 0
-
-    def batch_at(self, step: int) -> dict:
-        import jax.numpy as jnp
-        rng = np.random.default_rng((self.seed << 20) ^ step)
-        toks = rng.integers(0, self.vocab_size,
-                            (self.batch, self.seq_len + 1))
-        return {
-            "tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-            "labels": jnp.asarray(toks[:, 1:], jnp.int32),
-        }

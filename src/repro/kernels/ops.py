@@ -639,45 +639,6 @@ def join_pairs(method: str, *args, **kw):
     raise ValueError(f"unknown pair-emission method {method!r}")
 
 
-def flash_attention(q, k, v, window=None, blocks=None, interpret=None):
-    """Causal flash attention. q,k,v (B, L, H, D), kv pre-expanded to H.
-
-    Pads L to block multiples, merges (B, H) into the grid dim, slices the
-    padding back off. Inference-path only (no backward kernel yet).
-    """
-    from . import flash_attention as _fa
-    interpret = _interpret_default() if interpret is None else interpret
-    b, l, h, d = q.shape
-    blocks = blocks or _fa.DEFAULT_BLOCKS
-    bq, bk = min(blocks[0], l), min(blocks[1], l)
-    mult = max(bq, bk)
-    pad = (-l) % mult
-    def prep(x):
-        x = jnp.moveaxis(x, 2, 1).reshape(b * h, l, d)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    o = _fa.flash_attention_bhld(
-        prep(q), prep(k), prep(v), scale=d ** -0.5, window=window,
-        l_real=l, blocks=(bq, bk), interpret=interpret)
-    o = o[:, :l].reshape(b, h, l, d)
-    return jnp.moveaxis(o, 1, 2)
-
-
-def flash_attention_ref(q, k, v, window=None):
-    """Full-softmax oracle for the flash kernel (same masks, f32 math)."""
-    b, l, h, d = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (d ** -0.5)
-    qp = jnp.arange(l)[:, None]
-    kp = jnp.arange(l)[None, :]
-    mask = kp <= qp
-    if window is not None:
-        mask &= kp > (qp - window)
-    s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)
-                      ).astype(q.dtype)
-
-
 def _pack_bitmaps(padded: jax.Array, universe: int) -> jax.Array:
     """(rows, L) int32 element lists (-1 pad) -> (rows, W) uint32 bitmaps.
 

@@ -3,7 +3,7 @@
 The LFVT is built once and queried many times — the LLM-training dedup
 use case is really a *service*: a device-resident FlatLFVT corpus
 answering "is this doc a near-dup?" for a request stream. This engine
-runs it in the continuous-batching idiom of ``serve/engine.py``:
+serves it as a request queue in padded micro-batches:
 
   * ``submit`` enqueues a request (one element set) and returns a
     request id immediately;

@@ -8,6 +8,7 @@ import repro
 from repro.core.join import brute_force_join
 from repro.core.planner import JoinPlan, JoinStats, PlannerError
 from repro.core.sets import SetCollection
+from repro.data.synth import DATASETS, make_join_dataset
 
 
 def sample_inputs(seed=5, n=28, universe=600):
@@ -108,6 +109,22 @@ def test_join_measures_boundary(measure):
                               repro.as_collection(s_sets), 2 / 3,
                               measure=measure)
     assert set(res.pairs) == oracle
+
+
+@pytest.mark.parametrize("name", sorted(DATASETS))
+def test_join_auto_matches_oracle_on_dataset_profiles(name):
+    """The front door's default (``method='auto'``) returns exactly the
+    brute-force pairs on each synthetic dataset profile; S carries copies
+    of four R rows so no profile's result is empty."""
+    spec = DATASETS[name]
+    R, S = make_join_dataset(name, scale=40 / spec.n_sets, seed=1)
+    S = SetCollection.from_ragged(list(S.sets) + list(R.sets[:4]),
+                                  universe=S.universe)
+    oracle = brute_force_join(R, S, 0.5)
+    assert len(oracle) >= 4
+    res = repro.join(R, S, 0.5)
+    assert res.plan.requested == "auto"
+    assert res.pairs == oracle
 
 
 def test_join_stats_sharing_and_byte_compat():

@@ -35,6 +35,7 @@ from repro.core.planner import (AUTO_CANDIDATES, DEFAULT_COEFFS, JoinPlan,
                                 score_methods, validate_join_args)
 from repro.core.sets import SetCollection
 from repro.core.tile_join import cf_rs_join_device
+from repro.data.synth import DATASETS, make_join_dataset
 
 MEASURES = measure_names()
 
@@ -426,6 +427,51 @@ def test_probe_reads_the_memo_on_the_second_call():
         assert not arr.flags.writeable
         with pytest.raises(ValueError):
             arr[...] = 0
+
+
+def _profile(name, seed=1):
+    spec = DATASETS[name]
+    return make_join_dataset(name, scale=40 / spec.n_sets, seed=seed)
+
+
+@pytest.mark.parametrize("name", sorted(DATASETS))
+def test_probe_memoized_s_equals_fresh_on_dataset_profiles(name):
+    """On every synthetic dataset profile the probe against a resident S
+    (its element summary memoized) equals the probe against a fresh copy
+    of S, whose summary is computed from scratch, bit for bit."""
+    R, S = _profile(name)
+    S.element_summary()
+    assert S.has_rep("element_summary")
+    for t, measure in ((0.5, "jaccard"), (0.8, "cosine")):
+        fresh = SetCollection(list(S.sets), S.universe, S.ids.copy())
+        want = probe_features(R, fresh, t, measure)
+        got = probe_features(R, S, t, measure)
+        assert list(got) == list(want)
+        bad = {k: (got[k], want[k]) for k in want
+               if not _same_bits(got[k], want[k])}
+        assert not bad, bad
+
+
+def test_probe_row_subset_summarizes_afresh(monkeypatch):
+    """A routed row subset of S is summarized from its own rows: the
+    probe never reads S's whole-collection memo, and equals the probe
+    against a collection holding just those rows."""
+    R, S = _profile("kosarak")
+    S.element_summary()
+    rows = np.arange(0, len(S), 3)
+    sub = SetCollection([S.sets[int(i)] for i in rows], S.universe,
+                        S.ids[rows])
+    want = probe_features(R, sub, 0.5)
+
+    def no_memo():
+        raise AssertionError("a row subset read S's element-summary memo")
+
+    monkeypatch.setattr(S, "element_summary", no_memo)
+    got = probe_features(R, S, 0.5, s_rows=rows)
+    assert got["n"] == len(rows) < len(S)
+    bad = {k: (got[k], want[k]) for k in want
+           if not _same_bits(got[k], want[k])}
+    assert not bad, bad
 
 
 def test_plan_shard_methods_tracks_partition():

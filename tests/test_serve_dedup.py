@@ -7,6 +7,9 @@
     micro-batch or later batches — no longer both survive; the second
     is flagged against the first's freshly-admitted corpus row;
   * ``DedupPipeline.filter_stream`` end-to-end on token docs;
+  * ``DedupPipeline`` under every measure: ``filter_batch`` keeps exactly
+    the rows the brute-force join leaves, ``filter_stream`` exactly those
+    a sequential oracle (admit each survivor before the next doc) keeps;
   * async emission: ``on_result`` callback ordering, the ``results()``
     queue, and per-request latency/batch stats;
   * empty-corpus start, empty queue, validation errors.
@@ -17,6 +20,7 @@ import pytest
 from repro.core.join import brute_force_join
 from repro.core.sets import SetCollection
 from repro.data.pipeline import DedupPipeline
+from repro.data.synth import docs_to_sets
 from repro.serve.dedup import DedupResult, DedupServeEngine
 
 UNI = 40
@@ -130,6 +134,56 @@ def test_filter_batch_gap_vs_filter_stream():
     kept_stream, stats = pipe.filter_stream([docs])
     assert len(kept_stream[0]) == 1  # fixed: the second copy is dropped
     assert stats["dups"] == 1 and stats["admitted"] == 1
+
+
+def _near_dup_batch():
+    """20 curated token docs and a 17-doc batch: 10 fresh docs, 5 near
+    duplicates of curated docs (3 tokens changed) and 2 exact copies of
+    fresh docs later in the batch (duplicates only within the stream)."""
+    rng = np.random.default_rng(0)
+    curated_docs = rng.integers(0, 500, (20, 64))
+    fresh = rng.integers(0, 500, (10, 64))
+    dups = curated_docs[:5].copy()
+    dups[:, :3] = rng.integers(0, 500, (5, 3))  # near duplicates
+    batch = np.concatenate([fresh, dups, fresh[[1, 6]]])
+    return docs_to_sets(curated_docs, universe=500), batch
+
+
+def _sequential_keep(curated, docs, t, measure):
+    """Host oracle of ``filter_stream``: each doc is judged against the
+    curated corpus plus every survivor before it, then admitted if new."""
+    corpus = list(curated.sets)
+    keep = []
+    for i, doc in enumerate(docs):
+        d = docs_to_sets(doc[None], universe=curated.universe)
+        S = SetCollection.from_ragged(corpus, universe=curated.universe)
+        if not brute_force_join(d, S, t, measure):
+            keep.append(i)
+            corpus.append(d.sets[0])
+    return keep
+
+
+@pytest.mark.parametrize("mode", ["filter_batch", "filter_stream"])
+@pytest.mark.parametrize("measure", ["jaccard", "cosine", "dice", "overlap"])
+def test_dedup_pipeline_drops_near_duplicates(measure, mode):
+    curated, batch = _near_dup_batch()
+    t = 0.8
+    pipe = DedupPipeline(curated, threshold=t, n_shards=4, measure=measure)
+    if mode == "filter_batch":
+        kept, stats = pipe.filter_batch(batch)
+        dup_rows = {r for r, _ in brute_force_join(
+            docs_to_sets(batch, universe=500), curated, t, measure)}
+        want = [i for i in range(len(batch)) if i not in dup_rows]
+        assert stats["n_dropped"] == 5   # the stream's copies survive
+    else:
+        halves = [batch[:8], batch[8:]]  # the copies land in batch 2
+        kept_halves, stats = pipe.filter_stream(halves)
+        kept = np.concatenate(kept_halves)
+        want = _sequential_keep(curated, batch, t, measure)
+        assert stats["n_dropped"] == 7   # and are dropped here
+    np.testing.assert_array_equal(kept, batch[want])
+    assert stats["n_in"] == len(batch)
+    assert stats["n_dropped"] == len(batch) - len(want)
 
 
 def test_empty_corpus_start_admits():
